@@ -6,10 +6,15 @@ block-geometry mirror of the source:
 
 * **trace**    — the public entry point traces over the bench shapes from
   ``BENCH_kernels.json`` and produces the contracted output shapes/dtypes;
-* **divisibility** — padded dims divide exactly into the block grid, lane
-  blocks respect the kernel's declared lane unit (128 for vocab/class-tiled
-  kernels — the TPU f32 tile is (8, 128)), sublane blocks are multiples
-  of 8;
+* **divisibility** — padded dims divide exactly into the block grid, the
+  minor two dims of every block are (a multiple of 8 or the full dim, a
+  multiple of 128 or the full dim) as the Pallas TPU lowering requires,
+  lane blocks respect the kernel's declared lane unit (128 for
+  vocab/class-tiled kernels — the TPU f32 tile is (8, 128)), and sublane
+  blocks are multiples of 8. These checks read the declared geometry
+  mirror below, not the kernels' own ``BlockSpec``s: what the TPU compiler
+  accepts is proven by compiling each kernel for a v5e chip
+  (tests/test_tpu_compile.py);
 * **vmem**     — the per-grid-step VMEM footprint (in/out blocks rounded up
   to (8, 128) tile granularity, double-buffered, plus scratch) fits a
   configurable budget (default 8 MiB of the ~16 MB/core);
@@ -46,9 +51,11 @@ DEFAULT_VMEM_BUDGET = 8 * 1024 * 1024  # bytes; VMEM is ~16 MB/core
 KRN_EXPLAIN = {
     "KRN001": "kernel entry point failed to trace (jax.eval_shape) or "
               "produced shapes/dtypes outside its contract",
-    "KRN002": "block grid does not divide the padded bench shape, or a "
-              "block dimension violates the kernel's declared (sublane, "
-              "lane) alignment units",
+    "KRN002": "block grid does not divide the padded bench shape, a "
+              "block's minor two dims are not (multiple of 8 or full, "
+              "multiple of 128 or full), or a block dimension violates the "
+              "kernel's declared (sublane, lane) alignment units — all read "
+              "from the declared geometry mirror",
     "KRN003": "estimated per-grid-step VMEM footprint (double-buffered "
               "blocks + scratch at (8,128) tile granularity) exceeds the "
               "budget",
@@ -120,14 +127,15 @@ def _distill_geometry(s: dict) -> Geometry:
         tiled={
             "z": ((B, Np, Vp), (1, bn, bv)),
             "t": ((B, Np, Vp), (1, bn, bv)),
-            "y": ((B, Np), (1, bn)),
-            "loss": ((B, Np), (1, bn)),
+            # per-row vectors travel as (B, Np, 1) columns
+            "y": ((B, Np, 1), (1, bn, 1)),
+            "loss": ((B, Np, 1), (1, bn, 1)),
             "stats": ((B, Np, 2), (1, bn, 2)),
             # bwd pass reuses the fwd tiles plus g-in and dz-out
-            "g": ((B, Np), (1, bn)),
+            "g": ((B, Np, 1), (1, bn, 1)),
             "dz": ((B, Np, Vp), (1, bn, bv)),
         },
-        scratch=[(bn,)] * 5,
+        scratch=[(bn, 1)] * 5,
         lane_blocks=[("z", bv)],
         sublane_blocks=[("z", bn)],
     ) if m else None
@@ -166,10 +174,10 @@ def _skr_geometry(s: dict) -> Geometry:
         grid=(B, Np // bn, Cp // bc),
         tiled={
             "p": ((B, Np, Cp), (1, bn, bc)),
-            "pc": ((B, Np), (1, bn)),
-            "do": ((B, Np), (1, bn)),
-            "qb": ((B, Np), (1, bn)),
-            "label": ((B, Np), (1, bn)),
+            "pc": ((B, Np, 1), (1, bn, 1)),
+            "do": ((B, Np, 1), (1, bn, 1)),
+            "qb": ((B, Np, 1), (1, bn, 1)),
+            "label": ((B, Np, 1), (1, bn, 1)),
             "out": ((B, Np, Cp), (1, bn, bc)),
         },
         lane_blocks=[("p", bc)],
@@ -191,18 +199,20 @@ def _skr_abstract(s: dict):
 
 def _flash_geometry(s: dict) -> Geometry:
     B, S, Nh, H = s["B"], s["S"], s["Nh"], s["H"]
+    K = s.get("K", Nh)
     bq = min(s.get("block_q", 128), max(8, S))
     bk = min(s.get("block_k", 128), max(8, S))
     Sq, Sk = _roundup(S, bq), _roundup(S, bk)
     return Geometry(
         grid=(B, Nh, Sq // bq, Sk // bk),
+        # the wrapper moves heads out of the minor pair: (B, heads, S, H)
         tiled={
-            "q": ((B, Sq, Nh, H), (1, bq, 1, H)),
-            "k": ((B, Sk, Nh, H), (1, bk, 1, H)),
-            "v": ((B, Sk, Nh, H), (1, bk, 1, H)),
-            "o": ((B, Sq, Nh, H), (1, bq, 1, H)),
+            "q": ((B, Nh, Sq, H), (1, 1, bq, H)),
+            "k": ((B, K, Sk, H), (1, 1, bk, H)),
+            "v": ((B, K, Sk, H), (1, 1, bk, H)),
+            "o": ((B, Nh, Sq, H), (1, 1, bq, H)),
         },
-        scratch=[(bq,), (bq,), (bq, H)],
+        scratch=[(bq, 1), (bq, 1), (bq, H)],
         # head_dim is the lane axis; MXU-aligned means a multiple of 64
         # (64/128/256 per the kernel docstring) — declared unit 64 here,
         # the VMEM estimate still pads lanes to the full 128 tile
@@ -230,14 +240,15 @@ def _rwkv6_geometry(s: dict) -> Geometry:
     Tp = _roundup(T, chunk)
     return Geometry(
         grid=(B, Hh, Tp // chunk),
+        # the wrapper moves heads out of the minor pair: (B, Hh, T, hd)
         tiled={
-            "r": ((B, Tp, Hh, hd), (1, chunk, 1, hd)),
-            "k": ((B, Tp, Hh, hd), (1, chunk, 1, hd)),
-            "v": ((B, Tp, Hh, hd), (1, chunk, 1, hd)),
-            "w": ((B, Tp, Hh, hd), (1, chunk, 1, hd)),
-            "u": ((Hh, hd), (1, hd)),
+            "r": ((B, Hh, Tp, hd), (1, 1, chunk, hd)),
+            "k": ((B, Hh, Tp, hd), (1, 1, chunk, hd)),
+            "v": ((B, Hh, Tp, hd), (1, 1, chunk, hd)),
+            "w": ((B, Hh, Tp, hd), (1, 1, chunk, hd)),
+            "u": ((Hh, 1, hd), (1, 1, hd)),
             "s0": ((B, Hh, hd, hd), (1, 1, hd, hd)),
-            "y": ((B, Tp, Hh, hd), (1, chunk, 1, hd)),
+            "y": ((B, Hh, Tp, hd), (1, 1, chunk, hd)),
             "sT": ((B, Hh, hd, hd), (1, 1, hd, hd)),
         },
         scratch=[(hd, hd)],
@@ -420,6 +431,15 @@ def check_divisibility(contract: KernelContract, shape: dict) -> list[Finding]:
                     "KRN002", path, line,
                     f"{contract.name}.{name}: axis {axis} padded dim {dim} "
                     f"not divisible by block {blk} (shape {shape})",
+                    engine="kernel"))
+        # the Pallas TPU lowering's rule for the minor pair of a block
+        minor = zip((SUBLANE, LANE), padded[-2:], block[-2:])
+        for unit, dim, blk in (minor if len(block) >= 2 else ()):
+            if blk % unit and blk != dim:
+                out.append(Finding(
+                    "KRN002", path, line,
+                    f"{contract.name}.{name}: minor block dim {blk} is "
+                    f"neither a multiple of {unit} nor the full dim {dim}",
                     engine="kernel"))
     for name, blk in geo.lane_blocks:
         if blk % LANE:

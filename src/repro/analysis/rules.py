@@ -7,8 +7,9 @@ scope, and an ``explain`` text surfaced by ``python -m repro.analysis
 * the ``benchmarks/tables/scenarios.json`` gate requires event signatures
   to be a pure function of (scenario, seed) — hence no wall clock, no
   unseeded randomness, no hash-ordered iteration near event emission;
-* JAX-version portability routes through the ``pallas_compat`` /
-  ``launch.mesh`` shims — hence no raw Pallas/mesh API outside them;
+* Pallas compiler params, the interpret decision and mesh axis types
+  each have one home (``pallas_compat`` / ``launch.mesh``) — hence no raw
+  Pallas/mesh API outside them;
 * algorithm dispatch is registry-only (PR 3) — hence no duck-typed
   probing of the ``FLAlgorithm`` surface outside ``fl/api.py``;
 * tracing-off must stay zero-overhead and event-log-invisible (PR 7) —
@@ -330,14 +331,14 @@ class Arch001ShimRouting(Rule):
     title = "raw Pallas/mesh APIs only inside their shims"
     scope = ("src/repro/",)
     explain = (
-        "JAX-version compatibility is concentrated in two shims:\n"
-        "repro.kernels.pallas_compat (CompilerParams vs TPUCompilerParams,\n"
-        "interpret-mode resolution) and repro.launch.mesh.compat_mesh\n"
-        "(make_mesh axis_types). Kernel modules under src/repro/kernels/\n"
-        "may call pl.pallas_call directly but must import CompilerParams\n"
-        "from the shim; everything else goes through the wrappers. A raw\n"
-        "pltpu.CompilerParams or jax.make_mesh elsewhere reintroduces the\n"
-        "version skew the shims exist to absorb."
+        "Two modules own the raw APIs: repro.kernels.pallas_compat\n"
+        "(CompilerParams and the interpret-mode decision) and\n"
+        "repro.launch.mesh.auto_mesh (make_mesh with Auto axis types).\n"
+        "Kernel modules under src/repro/kernels/ may call pl.pallas_call\n"
+        "directly but must import CompilerParams from pallas_compat;\n"
+        "everything else goes through the wrappers. A raw\n"
+        "pltpu.CompilerParams or jax.make_mesh elsewhere splits those\n"
+        "decisions across the tree."
     )
 
     _PALLAS_CALL_OK = ("src/repro/kernels/",)
@@ -348,14 +349,13 @@ class Arch001ShimRouting(Rule):
         for node in ast.walk(ctx.tree):
             if isinstance(node, ast.ImportFrom) and node.module:
                 if node.module == "jax.experimental.pallas.tpu" and any(
-                    a.name in ("CompilerParams", "TPUCompilerParams")
-                    for a in node.names
+                    a.name == "CompilerParams" for a in node.names
                 ) and not ctx.path.startswith(self._COMPILER_PARAMS_OK):
                     yield self.finding(
                         ctx, node,
                         "import CompilerParams from "
                         "repro.kernels.pallas_compat, not from "
-                        "jax.experimental.pallas.tpu (version shim)",
+                        "jax.experimental.pallas.tpu",
                     )
                 continue
             if not isinstance(node, ast.Attribute):
@@ -369,12 +369,10 @@ class Arch001ShimRouting(Rule):
                 yield self.finding(
                     ctx, node,
                     "pl.pallas_call outside src/repro/kernels/ — kernels "
-                    "live there so the pallas_compat shim covers them",
+                    "live there, beside pallas_compat",
                 )
-            elif name in (
-                "jax.experimental.pallas.tpu.CompilerParams",
-                "jax.experimental.pallas.tpu.TPUCompilerParams",
-            ) and not ctx.path.startswith(self._COMPILER_PARAMS_OK):
+            elif name == "jax.experimental.pallas.tpu.CompilerParams" \
+                    and not ctx.path.startswith(self._COMPILER_PARAMS_OK):
                 yield self.finding(
                     ctx, node,
                     "raw pltpu CompilerParams reference; import it from "
@@ -386,7 +384,7 @@ class Arch001ShimRouting(Rule):
                 yield self.finding(
                     ctx, node,
                     "jax.make_mesh outside repro.launch.mesh; call "
-                    "compat_mesh so axis_types version skew stays shimmed",
+                    "auto_mesh so every mesh gets the same axis types",
                 )
 
 

@@ -32,8 +32,10 @@ for the whole batch):
                + beta * softmax(z) * ((z - logZ - t) - KL) ]
 
 Block shapes: lane dim (vocab) tiles of `block_v` (multiple of 128),
-sublane (rows) tiles of `block_n` (multiple of 8), batch blocks of 1. The
-running stats live in VMEM scratch and persist across the sequential
+sublane (rows) tiles of `block_n` (multiple of 8), batch blocks of 1.
+Per-row vectors (labels, losses, upstream grads) travel as (B, N, 1)
+columns so their blocks keep the TPU's minor-pair tiling. The running
+stats live in (block_n, 1) VMEM scratch and persist across the sequential
 vocab grid axis.
 """
 from __future__ import annotations
@@ -67,20 +69,20 @@ def _fwd_kernel(
 
     z = z_ref[0].astype(jnp.float32)  # (bn, bv)
     t = t_ref[0].astype(jnp.float32)
-    y = y_ref[0]  # (bn,)
+    y = y_ref[0]  # (bn, 1)
 
     m_old = m_s[...]
-    m_new = jnp.maximum(m_old, z.max(axis=-1))
+    m_new = jnp.maximum(m_old, z.max(axis=-1, keepdims=True))
     alpha = jnp.exp(m_old - m_new)
-    e = jnp.exp(z - m_new[:, None])
-    l_s[...] = l_s[...] * alpha + e.sum(-1)
-    sz_s[...] = sz_s[...] * alpha + (e * z).sum(-1)
-    st_s[...] = st_s[...] * alpha + (e * t).sum(-1)
+    e = jnp.exp(z - m_new)
+    l_s[...] = l_s[...] * alpha + e.sum(-1, keepdims=True)
+    sz_s[...] = sz_s[...] * alpha + (e * z).sum(-1, keepdims=True)
+    st_s[...] = st_s[...] * alpha + (e * t).sum(-1, keepdims=True)
     m_s[...] = m_new
 
     col = j * block_v + jax.lax.broadcasted_iota(jnp.int32, z.shape, 1)
-    hit = (col == y[:, None]).astype(jnp.float32)
-    zy_s[...] = zy_s[...] + (hit * z).sum(-1)
+    hit = (col == y).astype(jnp.float32)
+    zy_s[...] = zy_s[...] + (hit * z).sum(-1, keepdims=True)
 
     @pl.when(j == n_v - 1)
     def _fin():
@@ -89,7 +91,7 @@ def _fwd_kernel(
         ce = logz - zy_s[...]
         kl = sz_s[...] / l - logz - st_s[...] / l
         loss_ref[0] = label_weight * ce + beta * kl
-        stats_ref[0] = jnp.stack([logz, kl], axis=-1)
+        stats_ref[0] = jnp.concatenate([logz, kl], axis=-1)
 
 
 def _bwd_kernel(
@@ -99,15 +101,16 @@ def _bwd_kernel(
     j = pl.program_id(2)
     z = z_ref[0].astype(jnp.float32)
     t = t_ref[0].astype(jnp.float32)
-    y = y_ref[0]
-    logz = stats_ref[0, :, 0]
-    kl = stats_ref[0, :, 1]
+    y = y_ref[0]  # (bn, 1)
+    stats = stats_ref[0]
+    logz = stats[:, 0:1]
+    kl = stats[:, 1:2]
     g = g_ref[0]
-    sp = jnp.exp(z - logz[:, None])
+    sp = jnp.exp(z - logz)
     col = j * block_v + jax.lax.broadcasted_iota(jnp.int32, z.shape, 1)
-    onehot = (col == y[:, None]).astype(jnp.float32)
-    dz = label_weight * (sp - onehot) + beta * sp * ((z - logz[:, None] - t) - kl[:, None])
-    dz_ref[0] = (g[:, None] * dz).astype(dz_ref.dtype)
+    onehot = (col == y).astype(jnp.float32)
+    dz = label_weight * (sp - onehot) + beta * sp * ((z - logz - t) - kl)
+    dz_ref[0] = (g * dz).astype(dz_ref.dtype)
 
 
 def _pad(z, t, y, block_n, block_v):
@@ -116,7 +119,9 @@ def _pad(z, t, y, block_n, block_v):
     v_pad = (-V) % block_v
     z = jnp.pad(z, ((0, 0), (0, n_pad), (0, v_pad)), constant_values=NEG)
     t = jnp.pad(t, ((0, 0), (0, n_pad), (0, v_pad)))
-    y = jnp.pad(y, ((0, 0), (0, n_pad)))
+    # per-row vectors travel as (B, N, 1) columns: a (1, block_n, 1) block
+    # keeps the minor pair at (multiple of 8, full dim) for the TPU tiling
+    y = jnp.pad(y, ((0, 0), (0, n_pad)))[..., None]
     return z, t, y, N, V
 
 
@@ -141,23 +146,23 @@ def _distill_loss_fwd(
         in_specs=[
             pl.BlockSpec((1, block_n, block_v), lambda b, i, j: (b, i, j)),
             pl.BlockSpec((1, block_n, block_v), lambda b, i, j: (b, i, j)),
-            pl.BlockSpec((1, block_n), lambda b, i, j: (b, i)),
+            pl.BlockSpec((1, block_n, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_n), lambda b, i, j: (b, i)),
+            pl.BlockSpec((1, block_n, 1), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_n, 2), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Np), jnp.float32),
+            jax.ShapeDtypeStruct((B, Np, 1), jnp.float32),
             jax.ShapeDtypeStruct((B, Np, 2), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((block_n,), jnp.float32) for _ in range(5)],
+        scratch_shapes=[pltpu.VMEM((block_n, 1), jnp.float32) for _ in range(5)],
         compiler_params=CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
     )(z, t, y)
-    return loss[:, :N], stats[:, :N]
+    return loss[:, :N, 0], stats[:, :N]
 
 
 @functools.partial(
@@ -171,7 +176,7 @@ def _distill_loss_bwd(
     z, t, y, N, V = _pad(logits, teacher_logprobs, labels, block_n, block_v)
     B, Np, Vp = z.shape
     stats_p = jnp.pad(stats, ((0, 0), (0, Np - N), (0, 0)))
-    g_p = jnp.pad(g, ((0, 0), (0, Np - N)))
+    g_p = jnp.pad(g, ((0, 0), (0, Np - N)))[..., None]
     grid = (B, Np // block_n, Vp // block_v)
     dz = pl.pallas_call(
         functools.partial(
@@ -181,9 +186,9 @@ def _distill_loss_bwd(
         in_specs=[
             pl.BlockSpec((1, block_n, block_v), lambda b, i, j: (b, i, j)),
             pl.BlockSpec((1, block_n, block_v), lambda b, i, j: (b, i, j)),
-            pl.BlockSpec((1, block_n), lambda b, i, j: (b, i)),
+            pl.BlockSpec((1, block_n, 1), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_n, 2), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_n), lambda b, i, j: (b, i)),
+            pl.BlockSpec((1, block_n, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_n, block_v), lambda b, i, j: (b, i, j)),
         out_shape=jax.ShapeDtypeStruct((B, Np, Vp), logits.dtype),

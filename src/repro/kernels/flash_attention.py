@@ -57,9 +57,9 @@ def _kernel(
 
     @pl.when(reachable)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale  # (bq, H)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (bk, H)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32) * scale  # (bq, H)
+        k = k_ref[0, 0].astype(jnp.float32)  # (bk, H)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = q @ k.T  # (bq, bk)
         rows = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         cols = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -69,18 +69,18 @@ def _kernel(
         if window:
             mask &= cols > rows - window
         s = jnp.where(mask, s, NEG)
-        m_old = m_s[...]
-        m_new = jnp.maximum(m_old, s.max(-1))
+        m_old = m_s[...]  # (bq, 1) row columns
+        m_new = jnp.maximum(m_old, s.max(-1, keepdims=True))
         alpha = jnp.exp(m_old - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_s[...] = l_s[...] * alpha + p.sum(-1)
-        acc_s[...] = acc_s[...] * alpha[:, None] + p @ v
+        p = jnp.exp(s - m_new)
+        l_s[...] = l_s[...] * alpha + p.sum(-1, keepdims=True)
+        acc_s[...] = acc_s[...] * alpha + p @ v
         m_s[...] = m_new
 
     @pl.when(j == n_k - 1)
     def _fin():
         l = jnp.maximum(l_s[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_s[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_s[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -109,13 +109,15 @@ def flash_attention(
     Sk = k.shape[1]
     bq = min(block_q, max(8, Sq))
     bk = min(block_k, max(8, Sk))
+    # heads move out of the minor pair: the kernel sees (B, heads, S, H),
+    # so every block's minor pair is (sequence tile, full head_dim)
     q_pad = (-Sq) % bq
     k_pad = (-Sk) % bk
-    qp = jnp.pad(q, ((0, 0), (0, q_pad), (0, 0), (0, 0)))
-    kp = jnp.pad(k, ((0, 0), (0, k_pad), (0, 0), (0, 0)))
-    vp = jnp.pad(v, ((0, 0), (0, k_pad), (0, 0), (0, 0)))
-    n_q = qp.shape[1] // bq
-    n_k = kp.shape[1] // bk
+    qp = jnp.pad(q, ((0, 0), (0, q_pad), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
+    kp = jnp.pad(k, ((0, 0), (0, k_pad), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
+    vp = jnp.pad(v, ((0, 0), (0, k_pad), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
+    n_q = qp.shape[2] // bq
+    n_k = kp.shape[2] // bk
 
     grid = (B, N, n_q, n_k)
     out = pl.pallas_call(
@@ -125,15 +127,15 @@ def flash_attention(
         ),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bq, 1, H), lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, bk, 1, H), lambda b, h, i, j: (b, j, h // G, 0)),
-            pl.BlockSpec((1, bk, 1, H), lambda b, h, i, j: (b, j, h // G, 0)),
+            pl.BlockSpec((1, 1, bq, H), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bk, H), lambda b, h, i, j: (b, h // G, j, 0)),
+            pl.BlockSpec((1, 1, bk, H), lambda b, h, i, j: (b, h // G, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, H), lambda b, h, i, j: (b, i, h, 0)),
+        out_specs=pl.BlockSpec((1, 1, bq, H), lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, H), jnp.float32),
         ],
         compiler_params=CompilerParams(
@@ -141,4 +143,4 @@ def flash_attention(
         ),
         interpret=interpret,
     )(qp, kp, vp)
-    return out[:, :Sq]
+    return out.transpose(0, 2, 1, 3)[:, :Sq]

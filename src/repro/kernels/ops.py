@@ -1,11 +1,10 @@
-"""Jit'd public wrappers around the Pallas kernels with backend dispatch.
+"""Traced public wrappers around the Pallas kernels.
 
-On TPU the kernels run compiled (interpret=False); on CPU they run under the
-Pallas interpreter (bit-for-bit the same kernel body) or fall through to the
-pure-jnp oracle for speed in large test sweeps. Backend detection lives in
-``repro.kernels.pallas_compat.resolve_interpret`` — the kernels default to
-``interpret=None`` and auto-detect, so these wrappers no longer thread a
-hard-coded flag. The oracle in ref.py is always the numerics ground truth.
+Every wrapper runs the Pallas kernel itself: there is no fall-through to
+the jnp oracle. The kernels default to ``interpret=None``, which
+``repro.kernels.pallas_compat.resolve_interpret`` turns into compiled on a
+TPU and the Pallas interpreter (the same kernel body) on the CPU. The
+oracles in ref.py are the numerics ground truth for tests and benchmarks.
 """
 from __future__ import annotations
 
@@ -19,16 +18,11 @@ from repro.kernels.distill_loss import (
     distill_loss_batched as _distill_loss_batched,
 )
 from repro.kernels.flash_attention import flash_attention as _flash
-from repro.kernels.pallas_compat import has_tpu_backend
 from repro.kernels.rwkv6_scan import rwkv6_scan as _rwkv6
 from repro.kernels.skr_rectify import (
     skr_rectify as _skr,
     skr_rectify_batched as _skr_batched,
 )
-
-
-def on_tpu() -> bool:
-    return has_tpu_backend()
 
 
 def _traced(kernel: str, fn, *args):
@@ -110,7 +104,7 @@ def flash_attention(q, k, v, *, causal=True, window=0, q_offset=0,
 def rwkv6_scan(r, k, v, w, u, s0, *, chunk: int = 64):
     return _traced(
         "rwkv6_scan",
-        lambda *a: _rwkv6(*a, chunk=chunk, interpret=not on_tpu()),
+        lambda *a: _rwkv6(*a, chunk=chunk),
         r, k, v, w, u, s0,
     )
 

@@ -1,35 +1,27 @@
-"""Version shims + backend probing for the Pallas TPU API.
+"""The Pallas TPU compiler params and the interpret-mode decision.
 
-JAX >= 0.5 exposes ``pltpu.CompilerParams``; 0.4.x called the same
-dataclass ``TPUCompilerParams`` (same fields, including
-``dimension_semantics``). Kernels import the name from here so they
-compile against either.
-
-``resolve_interpret`` is the single decision point for interpret mode:
-kernels default their ``interpret`` argument to ``None`` and resolve it
-here, so the Pallas kernels compile for real hardware when a TPU backend
-is present and fall back to the interpreter everywhere else — instead of
-each call site hard-coding ``interpret=True``.
+Kernels import ``CompilerParams`` from here (the ARCH001 rule keeps raw
+``pltpu`` references inside ``src/repro/kernels/``), and default their
+``interpret`` argument to ``None``, which ``resolve_interpret`` turns into
+a bool: interpret on the CPU backend, compile everywhere else. A backend
+that cannot be probed raises; it is never read as "no TPU".
 """
 from __future__ import annotations
 
 import jax
 from jax.experimental.pallas import tpu as pltpu
 
-CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+CompilerParams = pltpu.CompilerParams
 
 
 def has_tpu_backend() -> bool:
-    """True iff this process's default JAX backend is a real TPU."""
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # backend probing can fail in exotic setups
-        return False
+    """True iff this process's default JAX backend is a TPU."""
+    return jax.default_backend() == "tpu"
 
 
 def resolve_interpret(interpret: bool | None) -> bool:
-    """``None`` -> interpret exactly when no TPU backend is present;
+    """``None`` -> interpret exactly when the default backend is the CPU;
     an explicit bool is passed through untouched (tests force ``True``)."""
     if interpret is None:
-        return not has_tpu_backend()
+        return jax.default_backend() == "cpu"
     return bool(interpret)

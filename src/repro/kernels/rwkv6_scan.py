@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
+from repro.kernels.pallas_compat import CompilerParams, resolve_interpret
 
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref, s_s,
@@ -30,17 +30,25 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref, s_s,
     def _init():
         s_s[...] = s0_ref[0, 0].astype(jnp.float32)
 
-    u = u_ref[0].astype(jnp.float32)  # (hd,)
+    # (1, hd) x (1, hd) -> aᵀb on the MXU, at full f32 precision: the
+    # elementwise outer product it replaces was exact
+    outer = lambda a, b: jax.lax.dot_general(
+        a, b, (((0,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    u = u_ref[0].astype(jnp.float32)  # (1, hd)
+    u_rows = outer(u, jnp.ones_like(u))  # diag(u) as a row scale: u_i in row i
 
     def step(i, s):
-        r_i = r_ref[0, i, 0, :].astype(jnp.float32)  # (hd,)
-        k_i = k_ref[0, i, 0, :].astype(jnp.float32)
-        v_i = v_ref[0, i, 0, :].astype(jnp.float32)
-        w_i = w_ref[0, i, 0, :].astype(jnp.float32)
-        kv = k_i[:, None] * v_i[None, :]  # (hd, hd)
-        out = r_i @ (s + u[:, None] * kv)  # (hd,)
-        y_ref[0, i, 0, :] = out.astype(y_ref.dtype)
-        return w_i[:, None] * s + kv
+        row = pl.ds(i, 1)
+        r_i = r_ref[0, 0, row, :].astype(jnp.float32)  # (1, hd)
+        k_i = k_ref[0, 0, row, :].astype(jnp.float32)
+        v_i = v_ref[0, 0, row, :].astype(jnp.float32)
+        w_i = w_ref[0, 0, row, :].astype(jnp.float32)
+        kv = outer(k_i, v_i)  # (hd, hd)
+        out = jnp.dot(r_i, s + u_rows * kv, precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32)  # (1, hd)
+        y_ref[0, 0, row, :] = out.astype(y_ref.dtype)
+        return outer(w_i, jnp.ones_like(w_i)) * s + kv
 
     s = jax.lax.fori_loop(0, chunk, step, s_s[...])
     s_s[...] = s
@@ -51,13 +59,14 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref, s_s,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def rwkv6_scan(r, k, v, w, u, s0, *, chunk: int = 64, interpret: bool = True):
+def rwkv6_scan(r, k, v, w, u, s0, *, chunk: int = 64,
+               interpret: bool | None = None):
     """r/k/v/w: (B, T, H, hd); u: (H, hd); s0: (B, H, hd, hd).
 
     Returns (y (B, T, H, hd) fp32, sT (B, H, hd, hd) fp32). T is padded to a
-    chunk multiple with zeros (w=1 ⇒ padded steps leave the state intact...
-    padded w is 0 here, so the final state is taken from the last REAL step
-    by padding with w=1, k=0: state unchanged, outputs of padded rows unused).
+    chunk multiple with k=0 and w=1, so padded steps leave the state
+    unchanged and their outputs are dropped. ``interpret=None`` compiles
+    the kernel unless the backend is the CPU.
     """
     B, T, H, hd = r.shape
     t_pad = (-T) % chunk
@@ -68,29 +77,30 @@ def rwkv6_scan(r, k, v, w, u, s0, *, chunk: int = 64, interpret: bool = True):
     Tp = r.shape[1]
     n_t = Tp // chunk
     grid = (B, H, n_t)
+    # heads move out of the minor pair: the kernel sees (B, H, T, hd) and a
+    # (H, 1, hd) bonus, so every block's minor pair is (chunk, full hd)
+    r, k, v, w = (x.transpose(0, 2, 1, 3) for x in (r, k, v, w))
+    u = u[:, None, :]
     y, sT = pl.pallas_call(
         functools.partial(_kernel, chunk=chunk, n_t=n_t),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, chunk, 1, hd), lambda b, h, t: (b, t, h, 0)),
-            pl.BlockSpec((1, chunk, 1, hd), lambda b, h, t: (b, t, h, 0)),
-            pl.BlockSpec((1, chunk, 1, hd), lambda b, h, t: (b, t, h, 0)),
-            pl.BlockSpec((1, chunk, 1, hd), lambda b, h, t: (b, t, h, 0)),
-            pl.BlockSpec((1, hd), lambda b, h, t: (h, 0)),
+        ] + [pl.BlockSpec((1, 1, chunk, hd), lambda b, h, t: (b, h, t, 0))] * 4 + [
+            pl.BlockSpec((1, 1, hd), lambda b, h, t: (h, 0, 0)),
             pl.BlockSpec((1, 1, hd, hd), lambda b, h, t: (b, h, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, chunk, 1, hd), lambda b, h, t: (b, t, h, 0)),
+            pl.BlockSpec((1, 1, chunk, hd), lambda b, h, t: (b, h, t, 0)),
             pl.BlockSpec((1, 1, hd, hd), lambda b, h, t: (b, h, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Tp, H, hd), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Tp, hd), jnp.float32),
             jax.ShapeDtypeStruct((B, H, hd, hd), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
         compiler_params=CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(r, k, v, w, u, s0)
-    return y[:, :T], sT
+    return y.transpose(0, 2, 1, 3)[:, :T], sT

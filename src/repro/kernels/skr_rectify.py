@@ -33,16 +33,15 @@ from repro.kernels.pallas_compat import CompilerParams, resolve_interpret
 def _kernel(p_ref, pc_ref, do_ref, qb_ref, label_ref, out_ref, *, block_c: int):
     j = pl.program_id(2)
     p = p_ref[0]  # (bn, bc)
-    pc = pc_ref[0]  # (bn,)
+    pc = pc_ref[0]  # (bn, 1) row columns
     do = do_ref[0]
     qb = qb_ref[0]
     label = label_ref[0]
     scale = (1.0 - qb) / jnp.maximum(1.0 - pc, 1e-12)
-    rect = p * scale[:, None]
+    rect = p * scale
     col = j * block_c + jax.lax.broadcasted_iota(jnp.int32, p.shape, 1)
-    is_label = col == label[:, None]
-    rect = jnp.where(is_label, qb[:, None], rect)
-    out_ref[0] = jnp.where(do[:, None] > 0, rect, p)
+    rect = jnp.where(col == label, qb, rect)
+    out_ref[0] = jnp.where(do > 0, rect, p)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_c", "interpret"))
@@ -70,14 +69,16 @@ def skr_rectify_batched(
     do = (mis & (cnt > 0)).astype(jnp.int32)
     qb = jnp.take_along_axis(qbar, labels, axis=-1)
 
-    # pad to tile multiples (batch blocks are 1 — no batch padding)
+    # pad to tile multiples (batch blocks are 1 — no batch padding); row
+    # scalars travel as (B, N, 1) columns so their (block_n, 1) blocks keep
+    # the minor pair at (multiple of 8, full dim) for the TPU tiling
     n_pad = (-N) % block_n
     c_pad = (-C) % block_c
     p_in = jnp.pad(probs, ((0, 0), (0, n_pad), (0, c_pad)))
-    pc_in = jnp.pad(p_c, ((0, 0), (0, n_pad)))
-    do_in = jnp.pad(do, ((0, 0), (0, n_pad)))
-    qb_in = jnp.pad(qb, ((0, 0), (0, n_pad)))
-    lb_in = jnp.pad(labels, ((0, 0), (0, n_pad)), constant_values=-1)
+    col = lambda x, fill=0: jnp.pad(
+        x, ((0, 0), (0, n_pad)), constant_values=fill)[..., None]
+    pc_in, do_in, qb_in = col(p_c), col(do), col(qb)
+    lb_in = col(labels, -1)
     _, Np, Cp = p_in.shape
 
     grid = (B, Np // block_n, Cp // block_c)
@@ -86,11 +87,7 @@ def skr_rectify_batched(
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_n, block_c), lambda b, i, j: (b, i, j)),
-            pl.BlockSpec((1, block_n), lambda b, i, j: (b, i)),
-            pl.BlockSpec((1, block_n), lambda b, i, j: (b, i)),
-            pl.BlockSpec((1, block_n), lambda b, i, j: (b, i)),
-            pl.BlockSpec((1, block_n), lambda b, i, j: (b, i)),
-        ],
+        ] + [pl.BlockSpec((1, block_n, 1), lambda b, i, j: (b, i, 0))] * 4,
         out_specs=pl.BlockSpec((1, block_n, block_c), lambda b, i, j: (b, i, j)),
         out_shape=jax.ShapeDtypeStruct((B, Np, Cp), probs.dtype),
         compiler_params=CompilerParams(

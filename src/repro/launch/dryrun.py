@@ -182,11 +182,7 @@ def run_one(
         t_compile = time.time() - t1
 
         ma = compiled.memory_analysis()
-        # cost_analysis returns a dict on new JAX, a one-per-computation
-        # list of dicts on 0.4.x
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
         hlo = compiled.as_text()
         coll = parse_collectives(hlo)
 

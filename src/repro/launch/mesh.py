@@ -9,22 +9,19 @@ from __future__ import annotations
 import jax
 
 
-def compat_mesh(shape, axes):
-    """jax.make_mesh across JAX versions: >=0.5 wants explicit axis_types
-    (Auto everywhere — we rely on shard_map/jit inference, not Explicit
-    sharding); 0.4.x has no such kwarg."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+def auto_mesh(shape, axes):
+    """jax.make_mesh with Auto axis types everywhere: the steps rely on
+    shard_map/jit sharding inference, not Explicit sharding."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh(*, data: int = 1, model: int = 1):
@@ -32,7 +29,7 @@ def make_host_mesh(*, data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = min(data, n)
     model = max(1, min(model, n // max(data, 1)))
-    return compat_mesh((data, model), ("data", "model"))
+    return auto_mesh((data, model), ("data", "model"))
 
 
 # TPU v5e hardware constants used by the roofline analysis.

@@ -25,6 +25,7 @@ from repro.checkpoint import save_pytree
 from repro.configs import get_arch, list_archs, reduced
 from repro.configs.base import FLConfig
 from repro.data.loader import token_batches
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import default_opts, make_train_step
 from repro.models import init_params
@@ -110,6 +111,7 @@ def main(argv=None):
     ap.add_argument("--use-kernels", action="store_true")
     ap.add_argument("--checkpoint", default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.fl:
         train_fl(args.algorithm, rounds=args.rounds,
                  num_clients=args.num_clients, num_edges=args.num_edges,

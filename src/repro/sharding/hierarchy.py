@@ -20,12 +20,8 @@ Semantics (tested vs the flat global mean):
 from __future__ import annotations
 
 import jax
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-try:  # jax >= 0.6
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 
 def _data_axes(mesh, edge_axis, cloud_axis):

@@ -129,6 +129,9 @@ def main(argv=None) -> int:
             print(f"{name:<18} {sc.description}")
         return 0
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     names = [s.strip() for s in args.scenario.split(",") if s.strip()]
     for name in names:
         try:

@@ -152,6 +152,25 @@ def test_divisibility_catches_corrupted_block():
     assert kc.check_divisibility(c, shape) == []
 
 
+def test_divisibility_catches_misaligned_minor_pair():
+    """A (1, 8) block over a (B, N) row array — the label layout the TPU
+    lowering refuses — is flagged even though 8 divides N."""
+    c = kc.CONTRACTS["skr_rectify"]
+    shape = dict(SHAPES["skr_rectify"])
+
+    def row_geometry(s):
+        geo = c.geometry(s)
+        (B, Np, _), _ = geo.tiled["label"]
+        geo.tiled["label"] = ((B, Np), (1, 8))
+        return geo
+
+    bad = dataclasses.replace(c, geometry=row_geometry)
+    findings = kc.check_divisibility(bad, shape)
+    assert {f.rule for f in findings} == {"KRN002"}
+    assert any("minor block dim 8 " in f.message for f in findings)
+    assert kc.check_divisibility(c, shape) == []
+
+
 def test_vmem_budget_is_enforced():
     c = kc.CONTRACTS["flash_attention"]
     shape = SHAPES["flash_attention"]

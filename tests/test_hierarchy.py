@@ -9,9 +9,9 @@ from repro.sharding.hierarchy import hier_grad_mean
 
 
 def test_single_device_fallback():
-    from repro.launch.mesh import compat_mesh
+    from repro.launch.mesh import auto_mesh
 
-    mesh = compat_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     x = {"w": jnp.arange(12.0).reshape(4, 3)}
     out = hier_grad_mean(x, mesh)
     assert jnp.allclose(out["w"], x["w"].mean(0))
@@ -23,8 +23,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.sharding.hierarchy import hier_grad_mean, edge_only_mean
 
-from repro.launch.mesh import compat_mesh
-mesh = compat_mesh((2, 2, 2), ("pod", "data", "model"))
+from repro.launch.mesh import auto_mesh
+mesh = auto_mesh((2, 2, 2), ("pod", "data", "model"))
 rng = np.random.default_rng(0)
 x = {"w": jnp.asarray(rng.normal(0, 1, (8, 5)), jnp.float32),
      "b": jnp.asarray(rng.normal(0, 1, (8,)), jnp.float32)}
@@ -47,6 +47,9 @@ def test_multidevice_staged_equals_flat():
     keeps the single real CPU device per the dry-run import contract)."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    # the child runs on virtual CPU devices; it must never reach for a chip
+    # that this test process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     res = subprocess.run(
         [sys.executable, "-c", MULTIDEV_SCRIPT],
         capture_output=True, text=True, env=env, timeout=300,

@@ -61,6 +61,9 @@ def test_dryrun_lowers_on_production_mesh():
     """The deliverable-(e) path, exercised end to end on two meshes."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    # the child runs on virtual CPU devices; it must never reach for a chip
+    # that this test process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     res = subprocess.run(
         [sys.executable, "-c", DRYRUN_SCRIPT],
         capture_output=True, text=True, env=env, timeout=480,
