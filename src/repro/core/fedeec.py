@@ -13,6 +13,7 @@ FedAgg (the INFOCOM'24 predecessor) is exactly this with SKR disabled
 """
 from __future__ import annotations
 
+from contextlib import nullcontext
 from typing import Callable
 
 import jax
@@ -27,7 +28,11 @@ from repro.core.topology import Tree
 from repro.fl.api import FLAlgorithm, WorkItem, register_algorithm
 from repro.models.autoencoder import decode, encode
 from repro.models.registry import get_fl_model
+from repro.obs.metrics import global_registry
+from repro.obs.trace import active_tracer
 from repro.optim import adamw_init, adamw_update
+
+_NO_SPAN = nullcontext()
 
 
 class FedEEC(FLAlgorithm):
@@ -97,6 +102,12 @@ class FedEEC(FLAlgorithm):
         # (node, peer, reason) of BSBODP pairs lost to faults — the
         # knowledge that never agglomerated (docs/robustness.md)
         self.failed_pairs: list[tuple[str, str, str]] = []
+        # always-on BSBODP counters (docs/observability.md): calls of the
+        # jitted steps, and the bytes of host arrays sent to the device
+        reg = global_registry()
+        self._jit_calls = {step: reg.counter("fl_jit_calls_total", step=step)
+                           for step in ("decode", "teacher", "student")}
+        self._h2d_bytes = reg.counter("fl_h2d_bytes_total")
         self._init_phase()
 
     # ------------------------------------------------------------------ init
@@ -137,13 +148,13 @@ class FedEEC(FLAlgorithm):
         apply_fn = get_fl_model(model_name)[1]
         T = self.cfg.temperature
 
-        def fn(params, skr_state, bridge_x, labels):
+        def bsbodp_teacher(params, skr_state, bridge_x, labels):
             z = apply_fn(params, bridge_x)
             probs = jax.nn.softmax(z / T, axis=-1)
             new_state, q = skr_process_batch(skr_state, probs, labels)
             return probs, q, new_state
 
-        return fn
+        return bsbodp_teacher
 
     def _teacher_fn(self, model_name):
         key = ("teacher", model_name)
@@ -170,21 +181,23 @@ class FedEEC(FLAlgorithm):
                 zb = apply_fn(p, bx)
                 return bsbodp.leaf_loss(zl, ly, zb, by, tq, beta, gamma)
 
-            def fn(params, opt, bx, by, tq, lx, ly):
+            def bsbodp_student_leaf(params, opt, bx, by, tq, lx, ly):
                 l, g = jax.value_and_grad(loss_fn)(params, bx, by, tq, lx, ly)
                 params, opt = adamw_update(g, opt, params, lr=lr, weight_decay=0.0)
                 return params, opt, l
-        else:
-            def loss_fn(p, bx, by, tq):
-                zb = apply_fn(p, bx)
-                return bsbodp.non_leaf_loss(zb, by, tq, beta)
 
-            def fn(params, opt, bx, by, tq):
-                l, g = jax.value_and_grad(loss_fn)(params, bx, by, tq)
-                params, opt = adamw_update(g, opt, params, lr=lr, weight_decay=0.0)
-                return params, opt, l
+            return bsbodp_student_leaf
 
-        return fn
+        def loss_fn(p, bx, by, tq):
+            zb = apply_fn(p, bx)
+            return bsbodp.non_leaf_loss(zb, by, tq, beta)
+
+        def bsbodp_student(params, opt, bx, by, tq):
+            l, g = jax.value_and_grad(loss_fn)(params, bx, by, tq)
+            params, opt = adamw_update(g, opt, params, lr=lr, weight_decay=0.0)
+            return params, opt, l
+
+        return bsbodp_student
 
     def _student_fn(self, model_name, leaf: bool):
         key = ("student", model_name, leaf)
@@ -204,10 +217,17 @@ class FedEEC(FLAlgorithm):
     def _decode_fn(self):
         if "decode" not in self._step_cache:
             img = self.cfg.image_size
-            self._step_cache["decode"] = jax.jit(
-                lambda e: decode(self.auto, e, img)
-            )
+
+            def bsbodp_decode(e):
+                return decode(self.auto, e, img)
+
+            self._step_cache["decode"] = jax.jit(bsbodp_decode)
         return self._step_cache["decode"]
+
+    def _h2d(self, x: np.ndarray):
+        """``jnp.asarray`` of a host array, counted in ``fl_h2d_bytes_total``."""
+        self._h2d_bytes.inc(x.nbytes)
+        return jnp.asarray(x)
 
     # ------------------------------------------------------------- protocol
 
@@ -235,29 +255,40 @@ class FedEEC(FLAlgorithm):
         # fixed number of steps when cfg.distill_steps > 0 — pair_steps is
         # the single source of truth so the simulator prices what runs
         steps = self.pair_steps(v_s, v_t)
-        for _ in range(steps):
-            idx = self._bridge_choice(pair_node, n, bs)
-            e_b = jnp.asarray(eps[idx])
-            y_b = jnp.asarray(labels[idx])
-            bridge = dec_fn(e_b)
-            probs, q, new_skr = teacher(
-                self.params[v_t], self.skr[v_t], bridge, y_b
-            )
-            self.skr[v_t] = new_skr
-            tq = q if self.use_skr else probs
-            # teacher -> student: (|z| + 1) per sample (Table VII round term)
-            self.comm.record(link, bs * (cfg.num_classes + 1), "logits")
-            if is_leaf:
-                lx, ly = self.client_data[v_s]
-                li = self.rng.choice(len(ly), size=min(bs, len(ly)), replace=len(ly) < bs)
-                self.params[v_s], self.opt[v_s], _ = student(
-                    self.params[v_s], self.opt[v_s], bridge, y_b, tq,
-                    jnp.asarray(lx[li]), jnp.asarray(ly[li]),
-                )
-            else:
-                self.params[v_s], self.opt[v_s], _ = student(
-                    self.params[v_s], self.opt[v_s], bridge, y_b, tq
-                )
+        calls = self._jit_calls
+        tr = active_tracer()
+        with (tr.span("bsbodp.direction", cat="bsbodp", student=v_s, teacher=v_t,
+                      B=1, steps=steps) if tr is not None else _NO_SPAN):
+            for _ in range(steps):
+                with tr.span("bsbodp.inputs", cat="bsbodp") if tr is not None else _NO_SPAN:
+                    idx = self._bridge_choice(pair_node, n, bs)
+                    e_b = self._h2d(eps[idx])
+                    y_b = self._h2d(labels[idx])
+                with tr.span("bsbodp.decode", cat="bsbodp") if tr is not None else _NO_SPAN:
+                    bridge = dec_fn(e_b)
+                calls["decode"].inc()
+                with tr.span("bsbodp.teacher", cat="bsbodp") if tr is not None else _NO_SPAN:
+                    probs, q, new_skr = teacher(
+                        self.params[v_t], self.skr[v_t], bridge, y_b
+                    )
+                calls["teacher"].inc()
+                self.skr[v_t] = new_skr
+                tq = q if self.use_skr else probs
+                # teacher -> student: (|z| + 1) per sample (Table VII round term)
+                self.comm.record(link, bs * (cfg.num_classes + 1), "logits")
+                if is_leaf:
+                    with tr.span("bsbodp.inputs", cat="bsbodp") if tr is not None else _NO_SPAN:
+                        lx, ly = self.client_data[v_s]
+                        li = self.rng.choice(len(ly), size=min(bs, len(ly)),
+                                             replace=len(ly) < bs)
+                        local = (self._h2d(lx[li]), self._h2d(ly[li]))
+                else:
+                    local = ()
+                with tr.span("bsbodp.student", cat="bsbodp") if tr is not None else _NO_SPAN:
+                    self.params[v_s], self.opt[v_s], _ = student(
+                        self.params[v_s], self.opt[v_s], bridge, y_b, tq, *local
+                    )
+                calls["student"].inc()
 
     def _bridge_choice(self, node: str, n: int, bs: int) -> np.ndarray:
         """Bridge-sample index draw over ``node``'s embedding store. With
@@ -315,41 +346,54 @@ class FedEEC(FLAlgorithm):
         student = self._student_fn_batched(self.model_of[v_s0], is_leaf)
         links = [self.comm.link_kind(self.tree, c) for c in children]
 
-        P_t = tmap(lambda *xs: jnp.stack(xs), *[self.params[vt] for _, vt in pairs])
-        S_t = tmap(lambda *xs: jnp.stack(xs), *[self.skr[vt] for _, vt in pairs])
-        P_s = tmap(lambda *xs: jnp.stack(xs), *[self.params[vs] for vs, _ in pairs])
-        O_s = tmap(lambda *xs: jnp.stack(xs), *[self.opt[vs] for vs, _ in pairs])
+        calls = self._jit_calls
+        tr = active_tracer()
+        with (tr.span("bsbodp.direction", cat="bsbodp", student=[vs for vs, _ in pairs],
+                      teacher=[vt for _, vt in pairs], B=len(pairs), steps=steps)
+              if tr is not None else _NO_SPAN):
+            with tr.span("bsbodp.stack", cat="bsbodp") if tr is not None else _NO_SPAN:
+                P_t = tmap(lambda *xs: jnp.stack(xs), *[self.params[vt] for _, vt in pairs])
+                S_t = tmap(lambda *xs: jnp.stack(xs), *[self.skr[vt] for _, vt in pairs])
+                P_s = tmap(lambda *xs: jnp.stack(xs), *[self.params[vs] for vs, _ in pairs])
+                O_s = tmap(lambda *xs: jnp.stack(xs), *[self.opt[vs] for vs, _ in pairs])
 
-        for _ in range(steps):
-            idx = [self._bridge_choice(c, len(e[1]), bs)
-                   for c, e in zip(children, embs)]
-            e_b = np.stack([e[0][i] for e, i in zip(embs, idx)])
-            y_b = jnp.asarray(np.stack([e[1][i] for e, i in zip(embs, idx)]))
-            flat = dec_fn(jnp.asarray(e_b).reshape((-1,) + e_b.shape[2:]))
-            bridge = flat.reshape((len(pairs), bs) + flat.shape[1:])
-            probs, q, S_t = teacher(P_t, S_t, bridge, y_b)
-            tq = q if self.use_skr else probs
-            for link in links:
-                self.comm.record(link, bs * (cfg.num_classes + 1), "logits")
-            if is_leaf:
-                lxs, lys = [], []
-                for vs, _ in pairs:
-                    lx, ly = self.client_data[vs]
-                    li = self.rng.choice(len(ly), size=min(bs, len(ly)),
-                                         replace=len(ly) < bs)
-                    lxs.append(lx[li])
-                    lys.append(ly[li])
-                P_s, O_s, _ = student(
-                    P_s, O_s, bridge, y_b, tq,
-                    jnp.asarray(np.stack(lxs)), jnp.asarray(np.stack(lys)),
-                )
-            else:
-                P_s, O_s, _ = student(P_s, O_s, bridge, y_b, tq)
+            for _ in range(steps):
+                with tr.span("bsbodp.inputs", cat="bsbodp") if tr is not None else _NO_SPAN:
+                    idx = [self._bridge_choice(c, len(e[1]), bs)
+                           for c, e in zip(children, embs)]
+                    e_b = self._h2d(np.stack([e[0][i] for e, i in zip(embs, idx)]))
+                    y_b = self._h2d(np.stack([e[1][i] for e, i in zip(embs, idx)]))
+                with tr.span("bsbodp.decode", cat="bsbodp") if tr is not None else _NO_SPAN:
+                    flat = dec_fn(e_b.reshape((-1,) + e_b.shape[2:]))
+                    bridge = flat.reshape((len(pairs), bs) + flat.shape[1:])
+                calls["decode"].inc()
+                with tr.span("bsbodp.teacher", cat="bsbodp") if tr is not None else _NO_SPAN:
+                    probs, q, S_t = teacher(P_t, S_t, bridge, y_b)
+                calls["teacher"].inc()
+                tq = q if self.use_skr else probs
+                for link in links:
+                    self.comm.record(link, bs * (cfg.num_classes + 1), "logits")
+                if is_leaf:
+                    with tr.span("bsbodp.inputs", cat="bsbodp") if tr is not None else _NO_SPAN:
+                        lxs, lys = [], []
+                        for vs, _ in pairs:
+                            lx, ly = self.client_data[vs]
+                            li = self.rng.choice(len(ly), size=min(bs, len(ly)),
+                                                 replace=len(ly) < bs)
+                            lxs.append(lx[li])
+                            lys.append(ly[li])
+                        local = (self._h2d(np.stack(lxs)), self._h2d(np.stack(lys)))
+                else:
+                    local = ()
+                with tr.span("bsbodp.student", cat="bsbodp") if tr is not None else _NO_SPAN:
+                    P_s, O_s, _ = student(P_s, O_s, bridge, y_b, tq, *local)
+                calls["student"].inc()
 
-        for b, (vs, vt) in enumerate(pairs):
-            self.skr[vt] = tmap(lambda x, b=b: x[b], S_t)
-            self.params[vs] = tmap(lambda x, b=b: x[b], P_s)
-            self.opt[vs] = tmap(lambda x, b=b: x[b], O_s)
+            with tr.span("bsbodp.unstack", cat="bsbodp") if tr is not None else _NO_SPAN:
+                for b, (vs, vt) in enumerate(pairs):
+                    self.skr[vt] = tmap(lambda x, b=b: x[b], S_t)
+                    self.params[vs] = tmap(lambda x, b=b: x[b], P_s)
+                    self.opt[vs] = tmap(lambda x, b=b: x[b], O_s)
 
     def pair_steps(self, v1: str, v2: str) -> int:
         """Distill steps one direction of pair (v1, v2) runs — the single

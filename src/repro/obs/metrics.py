@@ -9,7 +9,6 @@ Naming conventions (see ``docs/observability.md``):
 
   sim_*      discrete-event scheduler quantities (one registry per SimEngine)
   fl_*       training-plane quantities (global registry)
-  kernel_*   accelerator dispatch quantities (global registry)
   *_total    monotonic counters; *_seconds durations; histograms for
              distributions, gauges for last-written values.
 
@@ -18,7 +17,8 @@ through ``json``), :meth:`MetricsRegistry.to_prometheus` (text exposition
 format), :meth:`MetricsRegistry.to_json`.
 
 The module-level :func:`global_registry` collects process-wide series that
-have no natural owner (eval wall time, kernel dispatch latency); the sim
+have no natural owner (eval wall time, BSBODP's jitted calls and
+host-to-device bytes); the sim
 engine keeps its own registry per run so replays start from zero.
 """
 from __future__ import annotations

@@ -1,17 +1,22 @@
 """Hierarchical tracing for the EECC stack.
 
 A :class:`Tracer` records a tree of spans — round → churn → dispatch group
-→ work item → kernel call — each carrying host wall time (``perf_counter``)
-and, where the simulator knows it, simulated time. Recording is append-only
-into plain lists; when no tracer is installed every instrumentation site is
-a single ``None`` check (see :func:`active_tracer`), so tracing-off runs
-add no measurable overhead and NEVER touch the event log (the
-``scenarios.json`` signature gate stays bit-identical either way).
+→ work item, and execute → BSBODP direction → step phase — each carrying
+host wall time (``perf_counter``) and, where the simulator knows it,
+simulated time. Recording is append-only into plain lists; when no tracer
+is installed every instrumentation site is a single ``None`` check (see
+:func:`active_tracer`), so tracing-off runs add no measurable overhead and
+NEVER touch the event log (the ``scenarios.json`` signature gate stays
+bit-identical either way).
 
 Two kinds of span:
 
 * **lived** spans (:meth:`Tracer.span`): a context manager timing a host
-  code block (dispatch groups, kernel calls, eval);
+  code block (dispatch groups, BSBODP step phases, eval). While the tracer
+  is the installed one (:func:`tracing`), each lived span also enters a
+  ``jax.profiler.TraceAnnotation("repro." + name)``: a no-op when no
+  profiler runs, and under ``jax.profiler.start_trace`` the span lands on
+  the trace's host plane, on the same clock as the device's events;
 * **computed** spans (:meth:`Tracer.add_span`): simulated-time intervals
   the scheduler derives rather than lives through (work items: the sim
   start/end the event queue will replay).
@@ -27,7 +32,7 @@ from __future__ import annotations
 
 import json
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -70,7 +75,8 @@ class Tracer:
              sim_t0: Optional[float] = None, **args):
         """Time a host code block as a span nested under the current one.
         Yields the :class:`Span`; callers may set ``sim_t1``/``args`` on it
-        before the block exits."""
+        before the block exits. While this tracer is installed the block is
+        also a profiler annotation named ``repro.<name>`` (args stay here)."""
         sp = Span(
             sid=len(self.spans),
             parent=self._stack[-1] if self._stack else -1,
@@ -80,7 +86,8 @@ class Tracer:
         self.spans.append(sp)
         self._stack.append(sp.sid)
         try:
-            yield sp
+            with (_annotation("repro." + name) if _ACTIVE is self else _NO_ANNOTATION):
+                yield sp
         finally:
             self._stack.pop()
             sp.t1_host = self._now()
@@ -181,6 +188,19 @@ class Tracer:
 # ---------------------------------------------------------------------------
 
 _ACTIVE: Optional[Tracer] = None
+_NO_ANNOTATION = nullcontext()
+_TRACE_ANNOTATION = None
+
+
+def _annotation(name: str):
+    """``jax.profiler.TraceAnnotation(name)``; jax is imported on first use
+    so the telemetry plane loads without it."""
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _TRACE_ANNOTATION = TraceAnnotation
+    return _TRACE_ANNOTATION(name)
 
 
 def active_tracer() -> Optional[Tracer]:

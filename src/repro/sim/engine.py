@@ -31,7 +31,7 @@ from typing import Callable, Optional
 from repro.core.topology import link_kind
 from repro.fl.api import FLAlgorithm, MigrationRefused, WorkItem
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import Tracer
+from repro.obs.trace import Tracer, tracing
 from repro.sim.churn import ChurnProcess
 from repro.sim.events import EventLog, EventQueue
 from repro.sim.faults import AttemptSchedule, FaultPlan, FaultProcess
@@ -675,7 +675,17 @@ class SimEngine:
         ``checkpoint_every`` > 0 snapshots to ``checkpoint_path`` after
         every N-th round; ``stop_after`` ends the run after that many
         total rounds WITHOUT the final-round eval (simulating a kill mid
-        run — the resumed run owns the remaining rounds)."""
+        run — the resumed run owns the remaining rounds).
+
+        The engine's tracer is the installed one (``repro.obs.tracing``)
+        for the duration, so the trainer's own spans nest under the
+        engine's whatever the entry point."""
+        with (tracing(self.tracer) if self.tracer is not None else nullcontext()):
+            return self._run(rounds, eval_fn, eval_every, checkpoint_every,
+                             checkpoint_path, stop_after)
+
+    def _run(self, rounds, eval_fn, eval_every, checkpoint_every,
+             checkpoint_path, stop_after) -> EventLog:
         tr = self.tracer
         prof = self._prof
         if prof is not None:
