@@ -103,10 +103,14 @@ class FedEEC(FLAlgorithm):
         # knowledge that never agglomerated (docs/robustness.md)
         self.failed_pairs: list[tuple[str, str, str]] = []
         # always-on BSBODP counters (docs/observability.md): calls of the
-        # jitted steps, and the bytes of host arrays sent to the device
+        # jitted distill steps; calls of a coalesced group's stack and
+        # unstack, apart, so that the steps' count stays three a distill
+        # step; and the bytes of host arrays sent to the device
         reg = global_registry()
         self._jit_calls = {step: reg.counter("fl_jit_calls_total", step=step)
                            for step in ("decode", "teacher", "student")}
+        self._jit_calls |= {step: reg.counter("fl_stack_calls_total", step=step)
+                            for step in ("stack", "unstack")}
         self._h2d_bytes = reg.counter("fl_h2d_bytes_total")
         self._init_phase()
 
@@ -214,12 +218,41 @@ class FedEEC(FLAlgorithm):
             )
         return self._step_cache[key]
 
+    def _stack_fn(self):
+        """One compiled copy of a coalesced group's per-node trees into
+        stacked ones: ``(P_t, S_t, P_s, O_s)``, each a list of B trees, to
+        four trees with a leading axis of B. jit keys on the structures and
+        shapes, so the one function serves every model pair and group size."""
+        if "stack" not in self._step_cache:
+            def bsbodp_stack(*groups):
+                return tuple(jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
+                             for trees in groups)
+
+            self._step_cache["stack"] = jax.jit(bsbodp_stack)
+        return self._step_cache["stack"]
+
+    def _unstack_fn(self):
+        """The inverse of ``_stack_fn`` for the trees a direction changes:
+        each stacked tree to a list of its B per-node trees, B read from a
+        leaf's leading axis while tracing."""
+        if "unstack" not in self._step_cache:
+            def bsbodp_unstack(*stacked):
+                B = jax.tree.leaves(stacked)[0].shape[0]
+                return tuple([jax.tree.map(lambda x, b=b: x[b], tree) for b in range(B)]
+                             for tree in stacked)
+
+            self._step_cache["unstack"] = jax.jit(bsbodp_unstack)
+        return self._step_cache["unstack"]
+
     def _decode_fn(self):
         if "decode" not in self._step_cache:
             img = self.cfg.image_size
 
             def bsbodp_decode(e):
-                return decode(self.auto, e, img)
+                # a coalesced group's (B, bs, E) embeddings decode as one
+                # flat batch, reshaped inside the program
+                x = decode(self.auto, e.reshape(-1, e.shape[-1]), img)
+                return x.reshape(e.shape[:-1] + x.shape[1:])
 
             self._step_cache["decode"] = jax.jit(bsbodp_decode)
         return self._step_cache["decode"]
@@ -334,7 +367,6 @@ class FedEEC(FLAlgorithm):
         instead of step-major within a pair).
         """
         cfg = self.cfg
-        tmap = jax.tree_util.tree_map
         v_s0, v_t0 = pairs[0]
         children = [self._pair_child(vs, vt) for vs, vt in pairs]
         embs = [self.embeddings[c] for c in children]
@@ -352,10 +384,10 @@ class FedEEC(FLAlgorithm):
                       teacher=[vt for _, vt in pairs], B=len(pairs), steps=steps)
               if tr is not None else _NO_SPAN):
             with tr.span("bsbodp.stack", cat="bsbodp") if tr is not None else _NO_SPAN:
-                P_t = tmap(lambda *xs: jnp.stack(xs), *[self.params[vt] for _, vt in pairs])
-                S_t = tmap(lambda *xs: jnp.stack(xs), *[self.skr[vt] for _, vt in pairs])
-                P_s = tmap(lambda *xs: jnp.stack(xs), *[self.params[vs] for vs, _ in pairs])
-                O_s = tmap(lambda *xs: jnp.stack(xs), *[self.opt[vs] for vs, _ in pairs])
+                P_t, S_t, P_s, O_s = self._stack_fn()(
+                    [self.params[vt] for _, vt in pairs], [self.skr[vt] for _, vt in pairs],
+                    [self.params[vs] for vs, _ in pairs], [self.opt[vs] for vs, _ in pairs])
+            calls["stack"].inc()
 
             for _ in range(steps):
                 with tr.span("bsbodp.inputs", cat="bsbodp") if tr is not None else _NO_SPAN:
@@ -364,8 +396,7 @@ class FedEEC(FLAlgorithm):
                     e_b = self._h2d(np.stack([e[0][i] for e, i in zip(embs, idx)]))
                     y_b = self._h2d(np.stack([e[1][i] for e, i in zip(embs, idx)]))
                 with tr.span("bsbodp.decode", cat="bsbodp") if tr is not None else _NO_SPAN:
-                    flat = dec_fn(e_b.reshape((-1,) + e_b.shape[2:]))
-                    bridge = flat.reshape((len(pairs), bs) + flat.shape[1:])
+                    bridge = dec_fn(e_b)
                 calls["decode"].inc()
                 with tr.span("bsbodp.teacher", cat="bsbodp") if tr is not None else _NO_SPAN:
                     probs, q, S_t = teacher(P_t, S_t, bridge, y_b)
@@ -390,10 +421,10 @@ class FedEEC(FLAlgorithm):
                 calls["student"].inc()
 
             with tr.span("bsbodp.unstack", cat="bsbodp") if tr is not None else _NO_SPAN:
-                for b, (vs, vt) in enumerate(pairs):
-                    self.skr[vt] = tmap(lambda x, b=b: x[b], S_t)
-                    self.params[vs] = tmap(lambda x, b=b: x[b], P_s)
-                    self.opt[vs] = tmap(lambda x, b=b: x[b], O_s)
+                skrs, params, opts = self._unstack_fn()(S_t, P_s, O_s)
+                for (vs, vt), skr, p, o in zip(pairs, skrs, params, opts):
+                    self.skr[vt], self.params[vs], self.opt[vs] = skr, p, o
+            calls["unstack"].inc()
 
     def pair_steps(self, v1: str, v2: str) -> int:
         """Distill steps one direction of pair (v1, v2) runs — the single

@@ -1,12 +1,14 @@
 """Pair coalescing: planner ordering, FedEEC batched-execution parity, and
 event-signature identity of batched vs serial scheduling."""
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs.fedeec_paper import paper_setting
 from repro.fl.api import WorkItem, create_algorithm
 from repro.fl.engine import build_problem
+from repro.obs.metrics import global_registry
 from repro.sim.engine import SimEngine, plan_groups
 from repro.sim.scenarios import get_scenario
 
@@ -131,6 +133,96 @@ def test_fedeec_execute_batch_matches_serial():
         assert _max_leaf_diff(a.opt[v], b.opt[v]) < 1e-5, v
         assert _max_leaf_diff(a.skr[v], b.skr[v]) < 1e-5, v
     assert a.comm.summary() == b.comm.summary()
+
+
+def _coalesced_group(trainer):
+    items = [it for it in trainer.work_items(0, lambda v: True)
+             if it.node in trainer.client_data]
+    group = max(plan_groups(items, trainer.batch_signature), key=len)
+    assert len(group) >= 2
+    return group
+
+
+def _stack_calls():
+    reg = global_registry()
+    return tuple(reg.counter("fl_stack_calls_total", step=s).value
+                 for s in ("stack", "unstack"))
+
+
+def _eager_stack(*groups):
+    return tuple(jax.tree.map(lambda *xs: jnp.stack(xs), *trees) for trees in groups)
+
+
+def _eager_unstack(*stacked):
+    B = jax.tree.leaves(stacked)[0].shape[0]
+    return tuple([jax.tree.map(lambda x, b=b: x[b], tree) for b in range(B)]
+                 for tree in stacked)
+
+
+def _assert_trees_equal(x, y, what):
+    assert jax.tree.structure(x) == jax.tree.structure(y), what
+    for u, v in zip(jax.tree.leaves(x), jax.tree.leaves(y)):
+        assert u.dtype == v.dtype and u.shape == v.shape, what
+        assert np.array_equal(np.asarray(u), np.asarray(v)), what
+
+
+def test_compiled_stack_matches_eager_bitwise_once_per_direction():
+    """The coalesced group's state moves in and out of stacked form by one
+    compiled program each way per direction, and every node's params, opt
+    and SKR state after the group are bitwise what per-leaf eager
+    ``jnp.stack`` and ``x[b]`` give for the same draws."""
+    cfg = _small_cfg()
+    eager, compiled = _fedeec(cfg), _fedeec(cfg)
+    eager.rng, compiled.rng = _ConstRng(), _ConstRng()
+    eager._stack_fn = lambda: _eager_stack
+    eager._unstack_fn = lambda: _eager_unstack
+    group = _coalesced_group(compiled)
+
+    eager.execute_batch(group)
+    before = _stack_calls()
+    compiled.execute_batch(group)
+    assert tuple(a - b for a, b in zip(_stack_calls(), before)) == (2, 2)
+
+    nodes = {it.node for it in group} | {it.peer for it in group}
+    for v in sorted(nodes):
+        _assert_trees_equal(eager.params[v], compiled.params[v], ("params", v))
+        _assert_trees_equal(eager.opt[v], compiled.opt[v], ("opt", v))
+        _assert_trees_equal(eager.skr[v], compiled.skr[v], ("skr", v))
+    assert eager.comm.summary() == compiled.comm.summary()
+
+    # a group of one runs the serial path and stacks nothing
+    before = _stack_calls()
+    compiled.execute_batch(group[:1])
+    assert _stack_calls() == before
+
+
+def test_decode_of_a_stacked_batch_is_the_flat_decode_reshaped():
+    """A coalesced group's (B, bs, E) embeddings decode inside one program
+    exactly as their flat (B * bs, E) batch does."""
+    trainer = _fedeec(_small_cfg())
+    dec = trainer._decode_fn()
+    e = jax.random.normal(jax.random.PRNGKey(1), (3, 4, trainer.cfg.embed_dim))
+    flat = dec(e.reshape(12, -1))
+    _assert_trees_equal(dec(e), flat.reshape((3, 4) + flat.shape[1:]), "decode")
+
+
+def test_second_execute_batch_of_a_signature_compiles_nothing():
+    trainer = _fedeec(_small_cfg())
+    group = _coalesced_group(trainer)
+    trainer.execute_batch(group)
+
+    compiles = []
+
+    def on_event(event, secs, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(secs)
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        trainer.execute_batch(group)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    assert compiles == []
 
 
 # --- scheduler identity ------------------------------------------------------

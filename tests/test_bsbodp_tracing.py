@@ -192,6 +192,7 @@ def test_each_bsbodp_step_lowers_to_a_named_module(problem):
 
     m = t.model_of
     assert module(t._decode_fn(), e) == "jit_bsbodp_decode"
+    assert module(t._decode_fn(), stack(e)) == "jit_bsbodp_decode"
     assert module(t._teacher_fn(m[cloud]), t.params[cloud], t.skr[cloud], x, y) == \
         "jit_bsbodp_teacher"
     assert module(t._teacher_fn_batched(m[edge]), stack(t.params[edge]), stack(t.skr[edge]),
@@ -203,3 +204,7 @@ def test_each_bsbodp_step_lowers_to_a_named_module(problem):
     assert module(t._student_fn_batched(m[leaf], True), stack(t.params[leaf]),
                   stack(t.opt[leaf]), stack(x), stack(y), stack(q), stack(lx), stack(y)) == \
         "jit_bsbodp_student_leaf"
+    group = [[t.params[edge]] * B, [t.skr[edge]] * B, [t.params[leaf]] * B, [t.opt[leaf]] * B]
+    assert module(t._stack_fn(), *group) == "jit_bsbodp_stack"
+    assert module(t._unstack_fn(), stack(t.skr[edge]), stack(t.params[leaf]),
+                  stack(t.opt[leaf])) == "jit_bsbodp_unstack"

@@ -16,6 +16,7 @@ from jax.sharding import SingleDeviceSharding
 
 # the chip_smoke.py configuration: 32x32 images, batch 8, 10 classes
 IMAGE, BATCH, CLASSES, STACK = 32, 8, 10, 4
+GROUP = 5  # the leaf groups of the benchmark's c50e5 cell
 HBM_BYTES = 16 * 1024**3  # one v5e chip
 
 
@@ -140,6 +141,22 @@ def test_coalesced_leaf_pair_compiles(trainer, one_chip):
              _spec(trainer.params["client0"], one_chip, STACK),
              _spec(trainer.opt["client0"], one_chip, STACK),
              img, lab, tq, img, lab)
+
+
+def test_coalesced_group_stack_and_unstack_compile(trainer, one_chip):
+    """The programs that move a group of GROUP coalesced pairs' state into
+    stacked form and back, edge as student: cnn1 clients teach ResNet-10
+    edges at base width 64 (the benchmark's ladder) with their AdamW state."""
+    from repro.models.cnn import init_resnet
+    from repro.optim import adamw_init
+
+    params = init_resnet(jax.random.PRNGKey(0), CLASSES, width=64)
+    opt = adamw_init(params)
+    teacher, skr = trainer.params["client0"], trainer.skr["client0"]
+    _compile(trainer._stack_fn(),
+             *[[_spec(t, one_chip)] * GROUP for t in (teacher, skr, params, opt)])
+    _compile(trainer._unstack_fn(),
+             *[_spec(t, one_chip, GROUP) for t in (skr, params, opt)])
 
 
 # -- Pallas kernels at the BENCH_kernels.json shapes -------------------------
