@@ -7,9 +7,12 @@ operations the model needs. Normalisations, activations, pooling and the
 losses are left out: they are a fraction of a percent of these models'
 work. A training step counts three forward passes (the forward pass and a
 backward pass of twice its cost); nothing is counted twice for
-recomputation.
+recomputation. Each model family counts its own forward pass from these
+primitives (``bench/families/``).
 """
 from __future__ import annotations
+
+from bench.spec import family
 
 
 def taps(n_in: int, k: int, stride: int) -> int:
@@ -31,30 +34,9 @@ def dense_flops(din: int, dout: int) -> int:
 
 
 def forward_flops(spec: dict, image: int, num_classes: int) -> int:
-    """One image through one entry of a configuration's ``models``."""
-    if spec["family"] == "cnn":
-        c1, c2, c3 = spec["widths"]
-        h = image
-        total = 0
-        for cin, cout in ((3, c1), (c1, c2), (c2, c3)):
-            total += conv_flops(h, 3, cin, cout)
-            h //= 2
-        return total + dense_flops(h * h * c3, num_classes)
-    width = spec["width"]
-    h = image
-    total = conv_flops(h, 3, 3, width)
-    cin = width
-    for stage, n in enumerate(spec["blocks_per_stage"]):
-        cout = width * 2 ** stage
-        for b in range(n):
-            stride = 2 if (b == 0 and stage > 0) else 1
-            total += conv_flops(h, 3, cin, cout, stride)
-            if stride != 1 or cin != cout:
-                total += conv_flops(h, 1, cin, cout, stride)
-            h = -(-h // stride)
-            total += conv_flops(h, 3, cout, cout)
-            cin = cout
-    return total + dense_flops(cin, num_classes)
+    """One image through one entry of a configuration's ``models``, as its
+    family (``bench/families/<family>.py``) counts it."""
+    return family(spec["family"]).forward_flops(spec, image, num_classes)
 
 
 def decode_flops(image: int, embed_dim: int, width: int = 16) -> int:

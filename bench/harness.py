@@ -12,15 +12,13 @@ import gc
 import glob
 import os
 import shutil
-import statistics
 import sys
 import tempfile
 import time
-from functools import partial
 
 import numpy as np
 
-from bench import check, flops, spec
+from bench import check, flops, program_obs, spec
 from bench.reference import PROBE_STEPS
 from bench.spec import Cell
 
@@ -52,24 +50,14 @@ class CompileLog:
 
 def register_models(config: dict) -> None:
     """Register the configuration's tier models with the program's model
-    registry, built by the program's own ``init_cnn``/``init_resnet`` at the
-    widths the file gives. Names already registered are the program's own."""
-    from repro.models import cnn
+    registry, each built by its family's ``register``
+    (``bench/families/<family>.py``) at the widths the file gives. Names
+    already registered are the program's own."""
     from repro.models.registry import FL_MODELS
 
     for name, m in config["models"].items():
-        if name in FL_MODELS:
-            continue
-        if m["family"] == "cnn":
-            init = partial(lambda key, num_classes=10, image=16, widths=(): cnn.init_cnn(
-                key, num_classes, widths=widths, image=image), widths=tuple(m["widths"]))
-            FL_MODELS[name] = (init, cnn.apply_cnn)
-        else:
-            bps = tuple(m["blocks_per_stage"])
-            init = partial(lambda key, num_classes=10, image=16, bps=(), width=0:
-                           cnn.init_resnet(key, num_classes, blocks_per_stage=bps,
-                                           width=width), bps=bps, width=m["width"])
-            FL_MODELS[name] = (init, partial(cnn.apply_resnet, blocks_per_stage=bps))
+        if name not in FL_MODELS:
+            spec.family(m["family"]).register(name, m, FL_MODELS)
 
 
 class Evaluator:
@@ -394,25 +382,6 @@ def _reduce_trace(cell: Cell, st: dict, w: dict) -> dict:
     return red
 
 
-def _span_ms_per_round(tracer, rounds):
-    """(scheduler, algorithm) host milliseconds per round from the
-    program's tracer: the round span minus its execute spans, and the
-    execute spans."""
-    by_round: dict[int, list] = {r: [0.0, 0.0] for r in rounds}
-    current = None
-    for sp in sorted(tracer.spans, key=lambda s: s.t0_host):
-        if sp.cat == "round":
-            current = sp.args.get("round")
-            if current in by_round:
-                by_round[current][0] += sp.host_dur
-        elif sp.cat == "execute" and current in by_round:
-            by_round[current][0] -= sp.host_dur
-            by_round[current][1] += sp.host_dur
-    sched = statistics.mean(v[0] for v in by_round.values()) * 1e3
-    algo = statistics.mean(v[1] for v in by_round.values()) * 1e3
-    return sched, algo
-
-
 def run_reference(cell: Cell, seed: int, groups, inputs, auto, dtype=None,
                   fault: str = ""):
     """The reference's side of the check on the same inputs."""
@@ -440,7 +409,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
         f"{st['trainer_init_s']:.3f}s); not counted: the benchmark's inputs and "
         f"the eval of the initial cloud {st['bench_s']:.3f}s, the program's side "
         f"of the check {st['check_s']:.3f}s")
+    c0 = program_obs.counters() if trace else None
     w = window(cell, st, seconds, trace)
+    c1 = program_obs.counters() if trace else None
     rounds = w["rounds"]
     span = rounds[-1]["t1"] - rounds[0]["t0"]
     for x in rounds:
@@ -459,8 +430,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     if trace:
         red = _reduce_trace(cell, st, w)
         record["trace"] = red
-        record["sched_ms"], record["algo_ms"] = _span_ms_per_round(
-            st["tracer"], [x["round"] for x in rounds])
+        ids = [x["round"] for x in rounds]
+        record["sched_ms"], record["algo_ms"] = program_obs.sched_algo_ms(st["tracer"], ids)
+        record["span_ms"] = program_obs.span_ms_per_round(st["tracer"], ids)
+        record["counters"] = program_obs.per_round(c0, c1, len(rounds))
         record["peak_flops"] = spec.peaks(device["kind"])["bf16_flops"]
         device.update(busy_s=red["busy_s"], window_s=red["window_s"])
         breakdown = {"device_ops": red["device_ops"], "idle_gaps": red["idle_gaps"]}

@@ -5,8 +5,9 @@ the seed, the configuration file and the inputs the benchmark hands to both
 sides (``make_inputs``, ``pretrain_autoencoder``) it builds, in
 straightforward ``jax.numpy``:
 
-* every node's tier model (CNN or ResNet with GroupNorm) from the seed, and
-  the leaves' embedding stores gathered up the tree;
+* every node's tier model from the seed, built by its family
+  (``bench/families/``: CNN, ResNet with GroupNorm) from the layers here,
+  and the leaves' embedding stores gathered up the tree;
 * BSBODP's directional steps: bridge decode, the teacher's temperature
   softmax with Self-Knowledge Rectification (Algorithm 2, row by row), the
   student's leaf or non-leaf loss, and AdamW;
@@ -21,11 +22,12 @@ order. Every matrix product runs at ``Precision.HIGHEST`` in float32; with
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench.spec import family
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -226,14 +228,10 @@ def apply_resnet(p, x, blocks_per_stage):
 
 
 def tier_model(spec: dict, num_classes: int, image: int):
-    """(init(key), apply(params, x)) of one entry of a config's ``models``."""
-    if spec["family"] == "cnn":
-        return (partial(init_cnn, num_classes=num_classes, image=image,
-                        widths=tuple(spec["widths"])), apply_cnn)
-    bps = tuple(spec["blocks_per_stage"])
-    return (partial(init_resnet, num_classes=num_classes, blocks_per_stage=bps,
-                    width=spec["width"]),
-            partial(apply_resnet, blocks_per_stage=bps))
+    """(init(key), apply(params, x)) of one entry of a config's ``models``,
+    built by its family (``bench/families/<family>.py``) from the layers
+    above."""
+    return family(spec["family"]).reference(spec, num_classes, image)
 
 
 # -- bridge autoencoder -----------------------------------------------------

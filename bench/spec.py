@@ -4,11 +4,14 @@
 mix. A configuration is ``bench/configs/<config>.json`` (its file is named in
 ``BENCHMARK.json``), a traffic mix ``bench/traffic/<traffic>.json``, a cell's
 limits for ``correct`` ``bench/limits/<cell>.json``, and a per-layer metric
-the function ``read`` in ``bench/metrics/<metric>.py``. Adding a cell means
-adding such files and an entry; no code changes.
+the function ``read`` in ``bench/metrics/<metric>.py``, and a tier-model
+family (a model's ``family`` in its configuration) ``bench/families/<family>.py``.
+Adding a cell, a metric or a model family means adding such files and an
+entry; no code changes.
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 from dataclasses import dataclass
@@ -55,14 +58,30 @@ def load_cell(name: str, benchmark: dict | None = None, root: Path = REPO) -> Ce
     )
 
 
-def metric_reader(name: str):
-    """The ``read(record)`` function of ``bench/metrics/<name>.py``."""
-    path = BENCH / "metrics" / f"{name}.py"
+def _load(kind: str, name: str):
+    path = BENCH / kind / f"{name}.py"
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+        f"bench_{kind}_" + name.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(name: str):
+    """The ``read(record)`` function of ``bench/metrics/<name>.py``."""
+    return _load("metrics", name).read
+
+
+@functools.cache
+def family(name: str):
+    """The module ``bench/families/<name>.py`` of a tier-model family, with
+    ``register(name, spec, registry)``, ``reference(spec, num_classes,
+    image)`` and ``forward_flops(spec, image, num_classes)``. Loaded once:
+    the operation count is looked up for every pair of every round."""
+    if not (BENCH / "families" / f"{name}.py").is_file():
+        known = sorted(p.stem for p in (BENCH / "families").glob("*.py"))
+        raise KeyError(f"unknown model family {name!r}; known: {known}")
+    return _load("families", name)
 
 
 def peaks(device_kind: str) -> dict:

@@ -22,20 +22,21 @@ The profiled round's stop of the profiler and trace write count against
 ``--seconds`` (as in a ``--trace 1`` run), so a window of about 90 seconds
 holds two or three unprofiled rounds after it.
 
-This is not part of a result line: no cell's metric reads it. The last line
-of standard output is one JSON object. Without a TPU it exits with code 2.
+A ``--trace 1`` run's per-layer metrics read the same spans, counters and
+programs through the same code (``bench/program_obs.py``,
+``trace_reduce.reduce_profile``'s ``by_program``); this adds the profiled
+round apart from the others and the idle gaps by the program's labels.
+The last line of standard output is one JSON object. Without a TPU it
+exits with code 2.
 """
 import time
 
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
-import bisect  # noqa: E402
-import heapq  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
-import statistics  # noqa: E402
 import sys  # noqa: E402
 from collections import defaultdict  # noqa: E402
 from pathlib import Path  # noqa: E402
@@ -44,35 +45,13 @@ REPO = Path(__file__).resolve().parent.parent
 sys.path[:0] = [str(REPO), str(REPO / "src")]
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
+from bench import program_obs  # noqa: E402
 from bench import trace_reduce as tr  # noqa: E402
 
 EVAL = "bench.eval"
 PROGRAM = "repro."
 # always-on counters of the program (``global_registry``)
-PROGRAM_COUNTERS = ("fl_jit_calls_total", "fl_h2d_bytes_total")
-
-
-def labels(ts, spans):
-    """For each time in ``ts``, the label of the shortest span (label,
-    start, end) covering it, the earliest listed of equal ones, or
-    ``harness``: ``trace_reduce.label_of`` in one sweep over the times in
-    order, the spans sorted once by start and the open ones kept in a heap
-    by length."""
-    order = sorted(range(len(spans)), key=lambda i: spans[i][1])
-    out = ["harness"] * len(ts)
-    heap: list[tuple[float, int, float]] = []
-    j = 0
-    for k in sorted(range(len(ts)), key=ts.__getitem__):
-        t = ts[k]
-        while j < len(order) and spans[order[j]][1] <= t:
-            i = order[j]
-            heapq.heappush(heap, (spans[i][2] - spans[i][1], i, spans[i][2]))
-            j += 1
-        while heap and heap[0][2] <= t:
-            heapq.heappop(heap)
-        if heap:
-            out[k] = spans[heap[0][1]][0]
-    return out
+PROGRAM_COUNTERS = ("fl_jit_calls_total", "fl_stack_calls_total", "fl_h2d_bytes_total")
 
 
 def program_label(name: str) -> str:
@@ -82,11 +61,13 @@ def program_label(name: str) -> str:
 
 
 def split_profile(planes) -> dict:
-    """The profiled round (``trace_reduce.WINDOW``) by compiled program and
-    by host label. Device times are clipped to the window and moved onto
-    the host clock as ``trace_reduce.reduce_profile`` does; a program's
-    device seconds sum its operations, so overlapping ones count twice."""
+    """The profiled round (``trace_reduce.WINDOW``) by compiled program
+    (``trace_reduce.reduce_profile``'s ``by_program``) and by host label,
+    with the reduction's window, busy time and launches. Device times are
+    clipped to the window and moved onto the host clock as
+    ``trace_reduce.reduce_profile`` does."""
     planes = list(planes)
+    red = tr.reduce_profile(planes)
     window, spans, evals, executes = None, [], [], []
     for pl in planes:
         if not pl.name.startswith("/host"):
@@ -104,77 +85,55 @@ def split_profile(planes) -> dict:
                     spans.append((e.name[len("bench."):], a, b))
                     if e.name == EVAL:
                         evals.append((a, b))
-    if window is None:
-        raise ValueError(f"no {tr.WINDOW!r} annotation in the trace")
     lo, hi = window
     devices = [pl for pl in planes if pl.name.startswith("/device:")
                and any(line.name == tr.OPS_LINE for line in pl.lines)]
-    if not devices:
-        raise ValueError("no device plane with an 'XLA Ops' line in the trace")
-    prog_s: dict[str, float] = defaultdict(float)
-    prog_n: dict[str, int] = defaultdict(int)
     idle: dict[str, list] = defaultdict(lambda: [0.0, 0])
     eval_n, skew = 0, None
     for k, pl in enumerate(devices):
-        modules = sorted(tr._events(pl, tr.MODULES_LINE), key=lambda m: m[1])
+        starts = sorted(a for _, a, _ in tr._events(pl, tr.MODULES_LINE))
         if skew is None:
-            skew = tr.clock_skew(sorted(executes), [m[1] for m in modules])
-        modules = [(tr.short_name(n), a + skew, b + skew) for n, a, b in modules]
-        starts = [m[1] for m in modules]
-        ivs = []
-        for _, a, b in tr._events(pl, tr.OPS_LINE):
-            a, b = max(a + skew, lo), min(b + skew, hi)
-            if b > a:
-                ivs.append((a, b))
-                j = bisect.bisect_right(starts, a) - 1
-                prog_s[modules[j][0] if j >= 0 and modules[j][2] >= a else "?"] += (b - a) * 1e-9
-        for prog, a, _ in modules:
+            skew = tr.clock_skew(sorted(executes), starts)
+        for a in starts:
+            a += skew
             if lo <= a < hi:
-                prog_n[prog] += 1
                 eval_n += any(ea <= a < eb for ea, eb in evals)
         if k == 0:
+            ivs = []
+            for _, a, b in tr._events(pl, tr.OPS_LINE):
+                a, b = max(a + skew, lo), min(b + skew, hi)
+                if b > a:
+                    ivs.append((a, b))
             gaps = tr.gaps(tr.union(ivs), lo, hi)
-            for (a, b), lab in zip(gaps, labels([(a + b) / 2 for a, b in gaps], spans)):
+            for (a, b), lab in zip(gaps, tr.labels([(a + b) / 2 for a, b in gaps], spans)):
                 idle[lab][0] += (b - a) * 1e-9
                 idle[lab][1] += 1
-    n = len(devices)
     return {
-        "by_program": {p: {"device_s": prog_s[p] / n, "launches": prog_n[p] / n}
-                       for p in sorted(set(prog_s) | set(prog_n))},
-        "eval_launches": eval_n / n,
+        "by_program": red["by_program"],
+        "eval_launches": eval_n / len(devices),
         "idle_by_label": dict(idle),
+        "window_s": red["window_s"], "busy_s": red["busy_s"], "launches": red["launches"],
     }
 
 
-def program_counters() -> dict:
+def program_counters(per: dict) -> dict:
     """Each of ``PROGRAM_COUNTERS`` that the program registers, summed over
-    its labels."""
-    from repro.obs.metrics import global_registry
-
+    its labels, from ``program_obs.per_round``'s changes."""
     out: dict[str, float] = {}
-    for key, m in global_registry().snapshot().items():
+    for key, v in per.items():
         name = key.split("{", 1)[0]
         if name in PROGRAM_COUNTERS:
-            out[name] = out.get(name, 0.0) + m["value"]
+            out[name] = out.get(name, 0.0) + v
     return out
 
 
 def span_ms_per_round(tracer, rounds) -> dict:
     """Host milliseconds per round over ``rounds`` of each ``bsbodp`` span
-    by name, of the ``execute`` spans (``algo``) and of the scheduler
-    (``sched``), as ``harness._span_ms_per_round`` finds them."""
-    from bench import harness
-
-    by_round: dict[int, dict] = {r: defaultdict(float) for r in rounds}
-    names, current = set(), None
-    for sp in sorted(tracer.spans, key=lambda s: s.t0_host):
-        if sp.cat == "round":
-            current = sp.args.get("round")
-        elif sp.cat == "bsbodp" and current in by_round:
-            by_round[current][sp.name] += sp.host_dur
-            names.add(sp.name)
-    out = {k: statistics.mean(v[k] for v in by_round.values()) * 1e3 for k in sorted(names)}
-    out["sched"], out["algo"] = harness._span_ms_per_round(tracer, rounds)
+    by name (``program_obs.span_ms_per_round``), of the ``execute`` spans
+    (``algo``) and of the scheduler (``sched``)."""
+    out = {k: v for k, v in program_obs.span_ms_per_round(tracer, rounds).items()
+           if k.startswith("bsbodp.")}
+    out["sched"], out["algo"] = program_obs.sched_algo_ms(tracer, rounds)
     return out
 
 
@@ -183,13 +142,11 @@ def measure(cell, st: dict, seconds: float, profile: bool) -> dict:
     and with ``profile`` the first round under the profiler."""
     from bench import harness
 
-    c0 = program_counters()
+    c0 = program_obs.counters()
     w = harness.window(cell, st, seconds, profile)
+    per = program_obs.per_round(c0, program_obs.counters(), len(w["rounds"]))
     rounds = [x["round"] for x in w["rounds"]]
-    out = {"rounds": rounds,
-           "counters": {k: (v - c0.get(k, 0.0)) / len(rounds)
-                        for k, v in program_counters().items()},
-           "spans": {}}
+    out = {"rounds": rounds, "counters": program_counters(per), "spans": {}}
     prof = w["profiled"]
     if prof is not None:
         out["spans"]["profiled"] = span_ms_per_round(st["tracer"], [prof["round"]])
@@ -200,9 +157,7 @@ def measure(cell, st: dict, seconds: float, profile: bool) -> dict:
             planes = list(ProfileData.from_file(prof["path"]).planes)
         finally:
             shutil.rmtree(prof["tmp"], ignore_errors=True)
-        red = tr.reduce_profile(planes)
-        out["trace"] = dict(split_profile(planes), window_s=red["window_s"],
-                            busy_s=red["busy_s"], launches=red["launches"])
+        out["trace"] = split_profile(planes)
     else:
         rest = rounds
     if rest:

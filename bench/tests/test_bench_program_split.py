@@ -9,7 +9,7 @@ from types import SimpleNamespace
 import pytest
 from bench_tiny import SEED, tiny_cell
 
-from bench import harness, split, trace_reduce
+from bench import harness, program_obs, split, trace_reduce
 from repro.obs.trace import Tracer
 
 FIXTURE = Path(__file__).parent / "fixtures" / "probe.xplane.pb"
@@ -78,7 +78,7 @@ def test_labels_are_those_of_a_scan_over_every_span(seed):
         a = rng.randrange(0, 1000)
         spans.append((f"s{i}", float(a), float(a + rng.choice([1, 5, 5, 20, 200]))))
     ts = [rng.uniform(-10, 1300) for _ in range(500)] + [s[1] for s in spans[:50]]
-    assert split.labels(ts, spans) == [trace_reduce.label_of(t, spans) for t in ts]
+    assert trace_reduce.labels(ts, spans) == [trace_reduce.label_of(t, spans) for t in ts]
 
 
 def test_bsbodp_spans_are_summed_by_name_per_round():
@@ -96,9 +96,41 @@ def test_bsbodp_spans_are_summed_by_name_per_round():
     assert ms["bsbodp.inputs"] <= ms["algo"]
 
 
+def test_split_reads_the_shared_reductions():
+    tr = Tracer()
+    with tr.span("round 1", cat="round", round=1):
+        with tr.span("execute", cat="execute"):
+            with tr.span("bsbodp.student", cat="bsbodp"):
+                time.sleep(1e-3)
+    with tr.span("eval", cat="eval", round=1):
+        pass
+    ms = program_obs.span_ms_per_round(tr, [1])
+    assert set(ms) == {"round", "execute", "bsbodp.student", "eval"}
+    assert split.span_ms_per_round(tr, [1]) == {
+        "bsbodp.student": ms["bsbodp.student"],
+        "sched": pytest.approx(ms["round"] - ms["execute"]), "algo": ms["execute"]}
+    r = split.split_profile([_host(), DEVICE])
+    red = trace_reduce.reduce_profile([_host(), DEVICE])
+    assert r["by_program"] == red["by_program"]
+    assert (r["window_s"], r["busy_s"], r["launches"]) == (
+        red["window_s"], red["busy_s"], red["launches"])
+
+
+def test_counters_are_summed_over_their_labels():
+    per = program_obs.per_round(
+        {'fl_jit_calls_total{step="decode"}': 3.0},
+        {'fl_jit_calls_total{step="decode"}': 9.0, 'fl_jit_calls_total{step="student"}': 6.0,
+         'fl_stack_calls_total{step="stack"}': 2.0, 'fl_stack_calls_total{step="unstack"}': 2.0,
+         "sim_other_total": 5.0}, 2)
+    assert split.program_counters(per) == {"fl_jit_calls_total": 6.0,
+                                           "fl_stack_calls_total": 2.0}
+
+
 def test_a_window_reads_the_programs_counters_and_spans():
     cell = tiny_cell()
     st = harness.setup(cell, SEED, True, time.perf_counter())
+    setup_stacks = sum(1 for sp in st["tracer"].spans
+                       if sp.name in ("bsbodp.stack", "bsbodp.unstack"))
     out = split.measure(cell, st, 0.0, False)
     assert len(out["rounds"]) == 1 and "trace" not in out
     ms = out["spans"]["unprofiled"]
@@ -115,6 +147,10 @@ def test_a_window_reads_the_programs_counters_and_spans():
             steps += sp.args["steps"]
     assert out["counters"]["fl_jit_calls_total"] == 3 * steps > 0
     assert out["counters"]["fl_h2d_bytes_total"] > 0
+    # one stack and one unstack call a coalesced direction
+    stacks = sum(1 for sp in st["tracer"].spans
+                 if sp.name in ("bsbodp.stack", "bsbodp.unstack")) - setup_stacks
+    assert out["counters"]["fl_stack_calls_total"] == stacks > 0
 
 
 def test_the_command_needs_a_tpu(monkeypatch):

@@ -13,8 +13,9 @@ import jax.numpy as jnp
 import pytest
 from bench_tiny import REPO
 
-from bench import flops, spec, trace_reduce
+from bench import flops, harness, reference, spec, trace_reduce
 from repro.models import autoencoder, cnn
+from repro.models.registry import FL_MODELS
 
 BENCHMARK = json.loads((REPO / "BENCHMARK.json").read_text())
 FIXTURE = Path(__file__).parent / "fixtures" / "probe.xplane.pb"
@@ -61,9 +62,93 @@ def test_every_per_layer_metric_has_a_reader(metric):
     value = read(record)
     assert value is None or value >= 0
     traced = dict(record, sched_ms=3.0, algo_ms=4.0, peak_flops=1e12,
+                  span_ms={"bsbodp.student": 1.5, "bsbodp.unstack": 0.5,
+                           "bsbodp.inputs": 0.7, "round": 9.0},
+                  counters={'fl_jit_calls_total{step="decode"}': 300.0,
+                            'fl_jit_calls_total{step="student"}': 300.0,
+                            'fl_stack_calls_total{step="stack"}': 20.0},
                   trace={"ops": 1e9, "window_s": 2.0, "busy_s": 0.5, "launches": 8,
-                         "rounds": 1})
+                         "rounds": 1,
+                         "by_program": {"jit_bsbodp_student": {"device_s": 0.25,
+                                                               "launches": 2}}})
     assert read(traced) >= 0
+
+
+@pytest.mark.parametrize("metric,value", [
+    ("student_host_ms_per_round", 1.5), ("unstack_host_ms_per_round", 0.5),
+    ("inputs_host_ms_per_round", 0.7), ("jit_step_calls_per_round", 600.0),
+    ("student_device_ms_per_round", 250.0)])
+def test_the_programs_spans_counters_and_programs_are_read(metric, value):
+    read = spec.metric_reader(metric)
+    record = {"span_ms": {"bsbodp.student": 1.5, "bsbodp.unstack": 0.5,
+                          "bsbodp.inputs": 0.7},
+              "counters": {'fl_jit_calls_total{step="decode"}': 300.0,
+                           'fl_jit_calls_total{step="student"}': 300.0,
+                           'fl_stack_calls_total{step="stack"}': 20.0},
+              "trace": {"rounds": 2, "by_program": {
+                  "jit_bsbodp_student": {"device_s": 0.4, "launches": 2},
+                  "jit_bsbodp_student_leaf": {"device_s": 0.1, "launches": 1},
+                  "jit_bsbodp_teacher": {"device_s": 9.0, "launches": 3}}}}
+    assert read(record) == pytest.approx(value)
+    assert read({"trace": {"rounds": 1, "by_program": {}}}) is None
+
+
+# -- model families ------------------------------------------------------------
+
+C50E5 = spec.load_json(spec.BENCH / "configs" / "fedeec-cifar10-c50e5.json")
+
+
+@pytest.mark.parametrize("model,ops", [("cnn1", 1_471_680), ("resnet10_w64", 448_540_160),
+                                       ("resnet18_w64", 963_718_656)])
+def test_operations_by_family_are_the_c50e5_models_counts(model, ops):
+    assert flops.forward_flops(C50E5["models"][model], 32, 10) == ops
+
+
+@pytest.mark.parametrize("lookup", [
+    lambda m: harness.register_models({"models": {"no_such_model": m}}),
+    lambda m: reference.tier_model(m, 10, 32),
+    lambda m: flops.forward_flops(m, 32, 10)],
+    ids=["register_models", "tier_model", "forward_flops"])
+def test_an_unknown_family_is_an_error(lookup):
+    with pytest.raises(KeyError, match="cnn"):
+        lookup({"family": "no_such_family"})
+
+
+NEW_FAMILY = """
+import jax.numpy as jnp
+
+
+def register(name, spec, registry):
+    registry[name] = (lambda key, num_classes=10, image=16: {"w": jnp.ones((3, num_classes))},
+                      lambda p, x: x.mean(axis=(1, 2)) @ p["w"])
+
+
+def reference(spec, num_classes, image):
+    return (lambda key: {"w": jnp.ones((3, num_classes))},
+            lambda p, x: x.mean(axis=(1, 2)) @ p["w"])
+
+
+def forward_flops(spec, image, num_classes):
+    return spec["ops"]
+"""
+
+
+def test_a_new_family_needs_only_a_file():
+    path = spec.BENCH / "families" / "made_up_family.py"
+    path.write_text(NEW_FAMILY)
+    m = {"family": "made_up_family", "ops": 1234}
+    try:
+        harness.register_models({"models": {"made_up_model": m}})
+        init, apply = reference.tier_model(m, 10, 8)
+        ops = flops.forward_flops(m, 8, 10)
+        prog_init, prog_apply = FL_MODELS.pop("made_up_model")
+    finally:
+        path.unlink()
+        spec.family.cache_clear()
+    assert ops == 1234
+    x = jnp.ones((2, 8, 8, 3))
+    assert apply(init(jax.random.PRNGKey(0)), x).shape == (2, 10)
+    assert prog_apply(prog_init(jax.random.PRNGKey(0)), x).shape == (2, 10)
 
 
 # -- peaks -------------------------------------------------------------------
@@ -145,6 +230,8 @@ def test_reduction_of_a_made_up_trace():
     assert gaps["execute (1 gaps)"] == pytest.approx(3e-6)
     assert gaps["eval (1 gaps)"] == pytest.approx(3e-6)
     assert gaps["harness (1 gaps)"] == pytest.approx(0.5e-6)
+    assert r["by_program"] == {"jit_fn": {"device_s": pytest.approx(2.5e-6), "launches": 2},
+                               "jit_g": {"device_s": pytest.approx(0.5e-6), "launches": 1}}
 
 
 def test_union_and_gaps():

@@ -6,10 +6,11 @@ around the profiled round. On each device plane (``/device:...``) the
 operations of the ``XLA Ops`` line give the busy intervals, whose union is
 the busy time, and the events of the ``XLA Modules`` line are the launches
 of compiled programs. An operation is named by its program and its own
-short name (``jit_fn/fusion.4``). The device's clock runs apart from the host's (about
-1.5 ms behind on a v5e): device events are moved onto the host clock by the
-median gap between each host ``tpu::System::Execute`` and the program launch
-it issued. The gaps between busy intervals are labelled by the innermost
+short name (``jit_fn/fusion.4``); each program's operations and launches
+are also summed apart (``by_program``). The device's clock runs apart from
+the host's (about 1.5 ms behind on a v5e): device events are moved onto the
+host clock by the median gap between each host ``tpu::System::Execute`` and
+the program launch it issued. The gaps between busy intervals are labelled by the innermost
 host span that covers their midpoint: the harness's own annotations from
 the trace, and the program's tracer spans, which the harness places on the
 trace's clock through the ``bench.clock`` marker.
@@ -17,6 +18,7 @@ trace's clock through the ``bench.clock`` marker.
 from __future__ import annotations
 
 import bisect
+import heapq
 from collections import defaultdict
 
 WINDOW = "bench.round"
@@ -58,6 +60,28 @@ def label_of(t, spans):
         if a <= t < b and (best is None or b - a < best[2] - best[1]):
             best = (name, a, b)
     return best[0] if best else "harness"
+
+
+def labels(ts, spans):
+    """For each time in ``ts``, the label of the shortest span (label,
+    start, end) covering it, the earliest listed of equal ones, or
+    ``harness``: ``label_of`` in one sweep over the times in order, the
+    spans sorted once by start and the open ones kept in a heap by length."""
+    order = sorted(range(len(spans)), key=lambda i: spans[i][1])
+    out = ["harness"] * len(ts)
+    heap: list[tuple[float, int, float]] = []
+    j = 0
+    for k in sorted(range(len(ts)), key=ts.__getitem__):
+        t = ts[k]
+        while j < len(order) and spans[order[j]][1] <= t:
+            i = order[j]
+            heapq.heappush(heap, (spans[i][2] - spans[i][1], i, spans[i][2]))
+            j += 1
+        while heap and heap[0][2] <= t:
+            heapq.heappop(heap)
+        if heap:
+            out[k] = spans[heap[0][1]][0]
+    return out
 
 
 def short_name(name: str) -> str:
@@ -118,6 +142,8 @@ def reduce_profile(planes, *, marker_perf=None, host_spans=(), top=10) -> dict:
         raise ValueError("no device plane with an 'XLA Ops' line in the trace")
     busy_ns, launches = [], []
     op_time: dict[str, float] = defaultdict(float)
+    prog_s: dict[str, float] = defaultdict(float)
+    prog_n: dict[str, int] = defaultdict(int)
     idle: dict[str, float] = defaultdict(float)
     idle_n: dict[str, int] = defaultdict(int)
     skews = []
@@ -135,12 +161,17 @@ def reduce_profile(planes, *, marker_perf=None, host_spans=(), top=10) -> dict:
                 j = bisect.bisect_right(starts, a) - 1
                 prog = short_name(modules[j][0]) if j >= 0 and modules[j][2] >= a else "?"
                 op_time[f"{prog}/{short_name(name)}"] += (b - a) * 1e-9
+                prog_s[prog] += (b - a) * 1e-9
         merged = union(ivs)
         busy_ns.append(sum(b - a for a, b in merged))
-        launches.append(sum(1 for _, a, _ in modules if lo <= a < hi))
+        launched = [short_name(name) for name, a, _ in modules if lo <= a < hi]
+        launches.append(len(launched))
+        for prog in launched:
+            prog_n[prog] += 1
         if k == 0:
-            for a, b in gaps(merged, lo, hi):
-                lab = label_of((a + b) / 2, spans)
+            idle_ivs = gaps(merged, lo, hi)
+            mids = [(a + b) / 2 for a, b in idle_ivs]
+            for (a, b), lab in zip(idle_ivs, labels(mids, spans)):
                 idle[lab] += (b - a) * 1e-9
                 idle_n[lab] += 1
     n = len(devices)
@@ -154,6 +185,10 @@ def reduce_profile(planes, *, marker_perf=None, host_spans=(), top=10) -> dict:
                              key=lambda kv: -kv[1])[:top],
         "idle_gaps": sorted(([f"{k} ({idle_n[k]} gaps)", v] for k, v in idle.items()),
                             key=lambda kv: -kv[1])[:top],
+        # device seconds (its operations summed, so overlapping ones count
+        # twice) and launches of each compiled program
+        "by_program": {p: {"device_s": prog_s[p] / n, "launches": prog_n[p] / n}
+                       for p in sorted(set(prog_s) | set(prog_n))},
     }
 
 
