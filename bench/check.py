@@ -12,6 +12,13 @@ logits before and after the round. ``readings`` compares two summaries. Norms ar
 norms, never as the norm of the difference: a first Adam step moves every
 element by about the learning rate whatever the sign of its gradient, so
 rounding flips signs without changing the norms.
+
+Each side reduces a snapshot of a node's state to these norms when it takes
+it (``change_norms``, ``v_norms``), one leaf at a time, and keeps the
+parameters before the round as a host copy (``to_host``): between snapshots
+the check holds no device array of a node's state, so a tier whose training
+state fills the chip can be checked. Scalars are read with ``.item()``:
+``float()`` of a device array keeps another device array alive.
 """
 from __future__ import annotations
 
@@ -33,51 +40,71 @@ def _paths(tree):
 
 
 @jax.jit
-def _v_norms(tree):
-    return jnp.stack([jnp.sqrt(jnp.sum(x.astype(jnp.float32))) for x in jax.tree.leaves(tree)])
+def _v_norm(x):
+    return jnp.sqrt(jnp.sum(x.astype(jnp.float32)))
 
 
 @jax.jit
-def _diff_norms(a, b):
-    return jnp.stack([jnp.linalg.norm((x.astype(jnp.float32) - y.astype(jnp.float32)).ravel())
-                      for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b))])
+def _diff_norm(x, y):
+    return jnp.linalg.norm((x.astype(jnp.float32) - y.astype(jnp.float32)).ravel())
 
 
-def _by_path(tree, values):
-    return dict(zip(_paths(tree), np.asarray(values, np.float64).tolist()))
+def pick(x, b):
+    """A leaf, or its row ``b`` of a coalesced group's stacked output."""
+    return x if b is None else x[b]
+
+
+def to_host(tree):
+    """A host copy of a tree of device arrays, copied so that it keeps no
+    device buffer alive where host and device share memory (the CPU)."""
+    return jax.tree.map(lambda x: np.array(x, copy=True), tree)
+
+
+def change_norms(tree, init, b=None) -> dict[str, float]:
+    """Per leaf path: the norm of ``tree - init`` in float32, ``init`` a host
+    copy brought to the device one leaf at a time; ``b`` picks a row of a
+    stacked ``tree``."""
+    return {k: _diff_norm(pick(x, b), y).item() for k, x, y in
+            zip(_paths(init), jax.tree.leaves(tree), jax.tree.leaves(init))}
+
+
+def v_norms(tree, b=None) -> dict[str, float]:
+    """Per leaf path: the root of the sum of Adam's ``v`` (the first
+    gradient's norm after one step)."""
+    return {k: _v_norm(pick(x, b)).item() for k, x in zip(_paths(tree), jax.tree.leaves(tree))}
+
+
+def end_norms(params: dict, opt: dict, init: dict) -> dict:
+    """node -> (per-leaf change norms, AdamW step count) at the round's end."""
+    return {v: (change_norms(params[v], init[v]), opt[v]["step"].item()) for v in params}
 
 
 def summarize(init, first, end, stores, logits_init, logits_round, steps, grad_norms=None,
               group0=()):
-    """Reduce one side of the comparison to host numbers.
+    """One side of the comparison, from snapshots already reduced.
 
-    ``init``: node -> parameters before the round; ``first``/``end``: node ->
-    (parameters, optimizer state) after the node's first dispatch group and
-    after the round; ``stores``: leaf -> embeddings; logits: (N, classes);
-    ``steps``: node -> (the losses of the first steps of its first direction
-    as student, Adam's ``v`` after the first, the parameters after the
-    last); ``grad_norms``: node -> per-leaf norms of its first gradient, and
-    ``group0``: the nodes of the round's first dispatch group (the
-    reference's side only)."""
-    s = {"init": {v: [np.asarray(x) for x in jax.tree.leaves(p)] for v, p in init.items()},
-         "first_delta": {}, "end_delta": {}, "end_step": {},
-         "loss": {}, "v1": {}, "delta3": {},
-         "stores": {v: np.asarray(e, np.float32) for v, e in stores.items()},
-         "logits_init": np.asarray(logits_init, np.float32),
-         "logits_round": np.asarray(logits_round, np.float32), "grad": {},
-         "group0": set(group0)}
-    for v, (p, opt) in first.items():
-        s["first_delta"][v] = _by_path(p, _diff_norms(p, init[v]))
-    for v, (losses, v1, p3) in steps.items():
-        s["loss"][v] = [float(x) for x in losses]
-        s["v1"][v] = _by_path(v1, _v_norms(v1))
-        s["delta3"][v] = _by_path(p3, _diff_norms(p3, init[v]))
-    for v, (p, opt) in end.items():
-        s["end_delta"][v] = _by_path(p, _diff_norms(p, init[v]))
-        s["end_step"][v] = int(opt["step"])
-    for v, g in (grad_norms or {}).items():
-        s["grad"][v] = _by_path(init[v], g)
-    return s
+    ``init``: node -> host copy of its parameters before the round;
+    ``first``: node -> per-leaf change norms after its first dispatch group;
+    ``end``: node -> (per-leaf change norms, step count) after the round
+    (``end_norms``); ``stores``: leaf -> embeddings; logits: (N, classes);
+    ``steps``: node -> (the losses of the first steps of its first
+    direction as student, ``v_norms`` after the first, ``change_norms``
+    after the last); ``grad_norms``: node -> per-leaf norms of its first
+    gradient, and ``group0``: the nodes of the round's first dispatch group
+    (the reference's side only)."""
+    return {"init": {v: jax.tree.leaves(p) for v, p in init.items()},
+            "first_delta": dict(first),
+            "end_delta": {v: d for v, (d, _) in end.items()},
+            "end_step": {v: n for v, (_, n) in end.items()},
+            "loss": {v: [float(x) for x in losses] for v, (losses, _, _) in steps.items()},
+            "v1": {v: v1 for v, (_, v1, _) in steps.items()},
+            "delta3": {v: d3 for v, (_, _, d3) in steps.items()},
+            "stores": {v: np.array(e, np.float32, copy=True) for v, e in stores.items()},
+            "logits_init": np.asarray(logits_init, np.float32),
+            "logits_round": np.asarray(logits_round, np.float32),
+            "grad": {v: dict(zip(_paths(init[v]), np.asarray(g, np.float64).tolist()))
+                     for v, g in (grad_norms or {}).items()},
+            "group0": set(group0)}
 
 
 def _kind(v: str) -> str:

@@ -31,3 +31,11 @@ def forward_flops(spec: dict, image: int, num_classes: int) -> int:
         total += conv_flops(h, 3, cin, cout)
         h //= 2
     return total + dense_flops(h * h * c3, num_classes)
+
+
+def param_count(spec: dict, image: int, num_classes: int) -> int:
+    """Parameters of the model: bias-free convolutions, a dense head with
+    bias."""
+    c1, c2, c3 = spec["widths"]
+    feat = (image // 8) ** 2 * c3
+    return 9 * (3 * c1 + c1 * c2 + c2 * c3) + feat * num_classes + num_classes

@@ -45,3 +45,19 @@ def forward_flops(spec: dict, image: int, num_classes: int) -> int:
             total += conv_flops(h, 3, cout, cout)
             cin = cout
     return total + dense_flops(cin, num_classes)
+
+
+def param_count(spec: dict, image: int, num_classes: int) -> int:
+    """Parameters of the model: bias-free convolutions, a scale and a bias
+    per channel of each GroupNorm, a dense head with bias."""
+    width = spec["width"]
+    total = 9 * 3 * width
+    cin = width
+    for stage, n in enumerate(spec["blocks_per_stage"]):
+        cout = width * 2 ** stage
+        for b in range(n):
+            total += 9 * cin * cout + 9 * cout * cout + 4 * cout
+            if (b == 0 and stage > 0) or cin != cout:
+                total += cin * cout
+            cin = cout
+    return total + cin * num_classes + num_classes

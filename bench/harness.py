@@ -16,6 +16,7 @@ import sys
 import tempfile
 import time
 
+import jax
 import numpy as np
 
 from bench import check, flops, program_obs, spec
@@ -35,8 +36,6 @@ class CompileLog:
     which each was reported."""
 
     def __init__(self):
-        import jax
-
         self.events: list[tuple[float, float]] = []
         jax.monitoring.register_event_duration_secs_listener(self._on)
 
@@ -65,16 +64,12 @@ class Evaluator:
     batches of 256, read back to the host; returns the accuracy."""
 
     def __init__(self, trainer, x_test, y_test):
-        import jax
-
         self.trainer, self.x, self.y = trainer, x_test, y_test
         self.fn = jax.jit(trainer.cloud_apply())
         self.times: list[float] = []
         self.logits = None
 
     def __call__(self) -> float:
-        import jax
-
         t0 = time.perf_counter()
         with jax.profiler.TraceAnnotation("bench.eval"):
             self.logits = self.logits_of(self.trainer.cloud_params())
@@ -94,30 +89,37 @@ class Probe:
     the first group it took part in, and the first ``STEPS`` student steps
     of each node's first direction as student: what the program's jitted
     student step returns (the loss, the optimizer state after the first
-    step, the parameters after the last). Installed for the checked round
-    only; the window runs the trainer's own methods."""
+    step, the parameters after the last). Each snapshot is reduced to its
+    norms against ``init`` (a host copy of the parameters before the round)
+    when it is taken, so the probe holds no device array of a node's state;
+    ``check_s`` is the host time spent reducing. Installed for the checked
+    round only; the window runs the trainer's own methods."""
 
     STEPS = PROBE_STEPS
 
-    def __init__(self, trainer):
-        self.t = trainer
+    def __init__(self, trainer, init: dict):
+        self.t, self.init = trainer, init
         self.groups: list[tuple[list[tuple[str, str]], int]] = []
         self.first: dict = {}
-        # node -> {"loss": [(loss, b)], "v1": (v, b), "p3": (params, b)};
-        # b indexes a coalesced group's stacked outputs, None for a serial step
+        # node -> {"loss": [floats], "v1": v_norms, "p3": change_norms}
         self.steps: dict = {}
+        self.check_s = 0.0
+        # (b, node) of this direction's fresh students; b indexes a
+        # coalesced group's stacked outputs, None for a serial step
         self._fresh: list[tuple[int, str]] = []
-        self._calls = 0
+        self._calls = self._last = 0
         self._depth = 0
 
-    def _direction(self, orig, students):
+    def _direction(self, orig):
         def run(arg, *rest):
             stacked = isinstance(arg, list)  # a coalesced group's pairs
-            self._fresh = [(b if stacked else None, v)
-                           for b, v in enumerate(students(arg, *rest)) if v not in self.steps]
+            pairs = arg if stacked else [(arg, *rest)]
+            self._fresh = [(b if stacked else None, v_s)
+                           for b, (v_s, _) in enumerate(pairs) if v_s not in self.steps]
             for _, v in self._fresh:
                 self.steps[v] = {"loss": []}
             self._calls = 0
+            self._last = min(self.STEPS, self.t.pair_steps(*pairs[0]))
             try:
                 orig(arg, *rest)
             finally:
@@ -131,28 +133,26 @@ class Probe:
             def step(*a):
                 out = fn(*a)
                 self._calls += 1
-                if self._calls <= self.STEPS:
+                if self._calls <= self._last and self._fresh:
+                    jax.block_until_ready(out)  # the step's own time is not the check's
+                    t0 = time.perf_counter()
                     params, opt, loss = out
                     for b, v in self._fresh:
                         rec = self.steps[v]
-                        rec["loss"].append((loss, b))
-                        rec["p3"] = (params, b)
+                        rec["loss"].append(check.pick(loss, b).item())
                         if self._calls == 1:
-                            rec["v1"] = (opt["v"], b)
+                            rec["v1"] = check.v_norms(opt["v"], b)
+                        if self._calls == self._last:
+                            rec["p3"] = check.change_norms(params, self.init[v], b)
+                    self.check_s += time.perf_counter() - t0
                 return out
             return step
         return get
 
     def step_records(self) -> dict:
-        """node -> (losses, Adam's ``v`` after the first step, parameters
-        after the last recorded step), sliced out of coalesced outputs."""
-        import jax
-
-        def pick(x, b):
-            return x if b is None else jax.tree.map(lambda a: a[b], x)
-
-        return {v: ([float(pick(l, b)) for l, b in r["loss"]], pick(*r["v1"]), pick(*r["p3"]))
-                for v, r in self.steps.items()}
+        """node -> (losses, ``v_norms`` after the first step, ``change_norms``
+        after the last recorded step)."""
+        return {v: (r["loss"], r["v1"], r["p3"]) for v, r in self.steps.items()}
 
     def _wrap(self, orig):
         def run(arg):
@@ -167,9 +167,12 @@ class Probe:
             finally:
                 self._depth -= 1
             if top:
-                for it in items:
-                    for v in (it.node, it.peer):
-                        self.first.setdefault(v, (self.t.params[v], self.t.opt[v]))
+                fresh = [v for it in items for v in (it.node, it.peer) if v not in self.first]
+                jax.block_until_ready([self.t.params[v] for v in fresh])
+                t0 = time.perf_counter()
+                for v in fresh:
+                    self.first[v] = check.change_norms(self.t.params[v], self.init[v])
+                self.check_s += time.perf_counter() - t0
         return run
 
     WRAPPED = ("execute", "execute_batch", "_bsbodp_directional",
@@ -179,10 +182,8 @@ class Probe:
         t = self.t
         t.execute = self._wrap(t.execute)
         t.execute_batch = self._wrap(t.execute_batch)
-        t._bsbodp_directional = self._direction(t._bsbodp_directional,
-                                                lambda v_s, v_t: [v_s])
-        t._bsbodp_directional_batched = self._direction(
-            t._bsbodp_directional_batched, lambda pairs: [v_s for v_s, _ in pairs])
+        t._bsbodp_directional = self._direction(t._bsbodp_directional)
+        t._bsbodp_directional_batched = self._direction(t._bsbodp_directional_batched)
         t._student_fn = self._student(t._student_fn)
         t._student_fn_batched = self._student(t._student_fn_batched)
         return self
@@ -194,10 +195,11 @@ class Probe:
 
 def _round_work(entries, trainer, cfg, config):
     """Pair items of one round from the program's event log: (attempted,
-    executed, distilled samples, operations of the distillation)."""
+    executed, distilled samples, the distillation's operations and least
+    HBM bytes by model and step, ``work[model][step]``)."""
     attempted = sum(1 for e in entries if e["kind"] in ("pair_start", "pair_skip"))
     done = [(e["node"], e["target"]) for e in entries if e["kind"] == "pair_done"]
-    samples = ops = 0
+    samples, work = 0, {}
     for a, b in done:
         child = a if trainer.tree.parent.get(a) == b else b
         bs = min(cfg.batch_size, len(trainer.embeddings[child][1]))
@@ -207,14 +209,14 @@ def _round_work(entries, trainer, cfg, config):
             local = 0
             if vs in trainer.client_data:
                 local = min(bs, len(trainer.client_data[vs][1]))
-            ops += flops.direction_flops(config, steps, bs, trainer.model_of[vt],
-                                         trainer.model_of[vs], local)
-    return attempted, len(done), samples, ops
+            for key, (f, nbytes) in flops.direction_work(
+                    config, steps, bs, trainer.model_of[vt], trainer.model_of[vs],
+                    local).items():
+                flops.add_work(work, key, f, nbytes)
+    return attempted, len(done), samples, work
 
 
 def _device_memory_peak() -> int:
-    import jax
-
     peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
              for d in jax.local_devices()]
     return int(max(peaks))
@@ -224,8 +226,6 @@ def _profile_round(run_round):
     """Run one round under ``jax.profiler`` with the Python tracer off.
     Returns the round's host start and end, the ``bench.clock`` marker's
     host time, the trace's path and its temporary directory."""
-    import jax
-
     tmp = tempfile.mkdtemp(prefix="bench-trace-")
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
@@ -274,8 +274,6 @@ def setup(cell: Cell, seed: int, trace: bool, t_start: float):
     the benchmark's own work: making the inputs (``make_problem``), the eval
     of the cloud's initial parameters that only the check reads, and reducing
     the program's side of the check."""
-    import jax
-
     from repro.configs.base import FLConfig
     from repro.core.topology import Tree
     from repro.fl.api import create_algorithm
@@ -304,15 +302,19 @@ def setup(cell: Cell, seed: int, trace: bool, t_start: float):
         tracer = Tracer()
     engine = SimEngine(trainer, sc, seed=cfg.seed, tracer=tracer)
     evaluator = Evaluator(trainer, inputs.x_test, inputs.y_test)
-    init = dict(trainer.params)
-    with Probe(trainer) as probe:
+    t0 = time.perf_counter()
+    init = check.to_host(trainer.params)
+    init_s = time.perf_counter() - t0
+    with Probe(trainer, init) as probe:
         engine.run(1, eval_fn=evaluator)
     logits_round = evaluator.logits
     # the round's compiled eval, on the cloud's parameters before the round
     t0 = time.perf_counter()
-    logits_init = evaluator.logits_of(init[trainer.tree.root])
+    logits_init = evaluator.logits_of(jax.device_put(init[trainer.tree.root]))
     bench_s += time.perf_counter() - t0
-    end = {v: (trainer.params[v], trainer.opt[v]) for v in trainer.params}
+    t0 = time.perf_counter()
+    end = check.end_norms(trainer.params, trainer.opt, init)
+    reduce_s = init_s + probe.check_s + time.perf_counter() - t0
     for r in range(1, cell.traffic["warmup_rounds"]):
         engine.run(r + 1, eval_fn=evaluator)
     jax.block_until_ready(trainer.params)
@@ -325,8 +327,8 @@ def setup(cell: Cell, seed: int, trace: bool, t_start: float):
     evaluator.times.clear()
     st.update(cfg=cfg, trainer=trainer, engine=engine, evaluator=evaluator,
               tracer=tracer, origin=origin, groups=probe.groups, side=side,
-              inputs=inputs, auto=auto, check_s=t_setup_end - t_chk, bench_s=bench_s,
-              setup_s=t_chk - t_start - bench_s,
+              inputs=inputs, auto=auto, check_s=t_setup_end - t_chk + reduce_s,
+              bench_s=bench_s, setup_s=t_chk - t_start - bench_s - reduce_s,
               setup_window=(t_start, t_setup_end), next_round=cell.traffic["warmup_rounds"])
     return st
 
@@ -354,10 +356,10 @@ def window(cell: Cell, st: dict, seconds: float, trace: bool) -> dict:
             ta = time.perf_counter()
             run_round()
             tb = time.perf_counter()
-        att, done, samples, ops = _round_work(engine.log.entries[n0:], trainer,
-                                              st["cfg"], cell.config)
+        att, done, samples, work = _round_work(engine.log.entries[n0:], trainer,
+                                               st["cfg"], cell.config)
         rounds.append({"round": r, "t0": ta, "t1": tb, "dur": tb - ta, "attempted": att,
-                       "executed": done, "samples": samples, "ops": ops})
+                       "executed": done, "samples": samples, "work": work})
         r += 1
     t_w1 = time.perf_counter()
     return {"rounds": rounds, "t0": t_w0, "t1": t_w1, "profiled": profiled,
@@ -376,8 +378,10 @@ def _reduce_trace(cell: Cell, st: dict, w: dict) -> dict:
         shutil.rmtree(prof["tmp"], ignore_errors=True)
     rnd = next(x for x in w["rounds"] if x["round"] == prof["round"])
     fl = cell.config["fl_config"]
-    red["ops"] = rnd["ops"] + fl["test_samples"] * flops.forward_flops(
-        cell.config["models"][fl["cloud_model"]], fl["image_size"], fl["num_classes"])
+    work = rnd["work"]
+    flops.add_work(work, (fl["cloud_model"], "eval"), *flops.eval_work(cell.config, EVAL_BATCH))
+    red["work"] = work
+    red["ops"] = sum(w["flops"] for steps in work.values() for w in steps.values())
     red["rounds"] = 1
     return red
 
@@ -391,10 +395,10 @@ def run_reference(cell: Cell, seed: int, groups, inputs, auto, dtype=None,
 
     ref = Reference(cell.config, seed, inputs, auto, dtype=dtype or jnp.float32,
                     fault=fault)
-    init = dict(ref.params)
+    init = check.to_host(ref.params)
     logits_init = ref.logits(ref.params[ref.root])
-    first, grad_norms, steps = ref.replay(groups)
-    end = {v: (ref.params[v], ref.opt[v]) for v in ref.params}
+    first, grad_norms, steps = ref.replay(groups, init)
+    end = check.end_norms(ref.params, ref.opt, init)
     logits_round = ref.logits(ref.params[ref.root])
     return check.summarize(init, first, end, {v: ref.stores[v][0] for v in ref.data},
                            logits_init, logits_round, steps=steps, grad_norms=grad_norms,
@@ -425,6 +429,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
         "rounds": rounds, "eval_s": w["eval_s"],
         "dispatches_per_round": w["dispatches"] / len(rounds),
         "setup_s": st["setup_s"], "window_s": span,
+        "memory_peak_bytes": device["memory_peak_bytes"],
     }
     breakdown = None
     if trace:
@@ -434,7 +439,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
         record["sched_ms"], record["algo_ms"] = program_obs.sched_algo_ms(st["tracer"], ids)
         record["span_ms"] = program_obs.span_ms_per_round(st["tracer"], ids)
         record["counters"] = program_obs.per_round(c0, c1, len(rounds))
-        record["peak_flops"] = spec.peaks(device["kind"])["bf16_flops"]
+        record["peaks"] = spec.peaks(device["kind"])
         device.update(busy_s=red["busy_s"], window_s=red["window_s"])
         breakdown = {"device_ops": red["device_ops"], "idle_gaps": red["idle_gaps"]}
     metrics = {}

@@ -6,4 +6,4 @@ def read(record):
     tr = record.get("trace")
     if not tr or not tr["ops"] or not tr["busy_s"]:
         return None
-    return 100.0 * tr["ops"] / tr["busy_s"] / record["peak_flops"]
+    return 100.0 * tr["ops"] / tr["busy_s"] / record["peaks"]["bf16_flops"]

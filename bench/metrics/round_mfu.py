@@ -6,4 +6,4 @@ def read(record):
     tr = record.get("trace")
     if not tr or not tr["ops"]:
         return None
-    return 100.0 * tr["ops"] / tr["window_s"] / record["peak_flops"]
+    return 100.0 * tr["ops"] / tr["window_s"] / record["peaks"]["bf16_flops"]

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import check
 from bench.spec import family
 
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -468,7 +469,9 @@ class Reference:
         student's parameters and optimizer state, the per-leaf norms of the
         student's first gradient, and of its first ``PROBE_STEPS`` steps the
         losses, Adam's ``v`` after the first and the parameters after the
-        last."""
+        last. The student's parameters and optimizer state are donated, so
+        that the scan's carry takes their place: a direction holds one copy
+        of the student's state besides ``v1`` and ``p3``, not two."""
         key = (teacher, student, leaf)
         if key in self._fns:
             return self._fns[key]
@@ -502,18 +505,19 @@ class Reference:
                 step, (skr, ps, opt, opt["v"], ps), (jnp.arange(e.shape[0]), e, y, lx, ly))
             return skr, ps, opt, gn[0], lv[:last + 1], v1, p3
 
-        self._fns[key] = jax.jit(fn)
+        self._fns[key] = jax.jit(fn, donate_argnums=(3, 4))
         return self._fns[key]
 
     def _child(self, a, b):
         return a if self.parent.get(a) == b else b
 
-    def _group(self, pairs, steps, grad_norms, probe):
+    def _group(self, pairs, steps, init, grad_norms, probe):
         """Both directions of a dispatch group: each pair's node learns from
         its peer, then each peer from its node. Rows are drawn step by step,
         all pairs' bridge rows first and then, for data-holding students,
         all pairs' local rows: the program's order for a coalesced group,
-        and for a group of one that of a serial pair."""
+        and for a group of one that of a serial pair. A node's first
+        direction as student is reduced to its norms at once (``check``)."""
         bsz = self.fl["batch_size"]
         for dirs in (list(pairs), [(b, a) for a, b in pairs]):
             rows = [([], [], [], []) for _ in dirs]
@@ -539,25 +543,29 @@ class Reference:
             for (vs, vt), r in zip(dirs, rows):
                 e, y, lx, ly = (np.stack(a) for a in r)
                 fn = self._direction_fn(self.model_of[vt], self.model_of[vs], vs in self.data)
-                self.skr[vt], self.params[vs], self.opt[vs], gn, lv, v1, p3 = fn(
-                    self.auto, self.params[vt], self.skr[vt], self.params[vs],
-                    self.opt[vs], jnp.asarray(e, self.dtype), jnp.asarray(y),
-                    jnp.asarray(lx, self.dtype), jnp.asarray(ly))
-                grad_norms.setdefault(vs, gn)
-                probe.setdefault(vs, (np.asarray(lv, np.float64).tolist(), v1, p3))
+                with jax.default_matmul_precision("highest"):
+                    self.skr[vt], self.params[vs], self.opt[vs], gn, lv, v1, p3 = fn(
+                        self.auto, self.params[vt], self.skr[vt], self.params[vs],
+                        self.opt[vs], jnp.asarray(e, self.dtype), jnp.asarray(y),
+                        jnp.asarray(lx, self.dtype), jnp.asarray(ly))
+                if vs not in probe:
+                    grad_norms[vs] = np.asarray(gn, np.float64)
+                    probe[vs] = (np.asarray(lv, np.float64).tolist(), check.v_norms(v1),
+                                 check.change_norms(p3, init[vs]))
 
-    def replay(self, groups):
-        """Run the recorded dispatch groups of the first round. Returns each
-        node's state after the first group it took part in, the per-leaf
-        norms of each node's first gradient, and the first steps of each
-        node's first direction as student (``_direction_fn``)."""
+    def replay(self, groups, init):
+        """Run the recorded dispatch groups of the first round. Returns, per
+        node and reduced against ``init`` (host copies of the parameters
+        before the round), the change norms after the first group it took
+        part in, the per-leaf norms of its first gradient, and the first
+        steps of its first direction as student (``_direction_fn``)."""
         first, grad_norms, probe = {}, {}, {}
-        with jax.default_matmul_precision("highest"):
-            for pairs, steps in groups:
-                self._group(pairs, steps, grad_norms, probe)
-                for a, b in pairs:
-                    for v in (a, b):
-                        first.setdefault(v, (self.params[v], self.opt[v]))
+        for pairs, steps in groups:
+            self._group(pairs, steps, init, grad_norms, probe)
+            for a, b in pairs:
+                for v in (a, b):
+                    if v not in first:
+                        first[v] = check.change_norms(self.params[v], init[v])
         return first, grad_norms, probe
 
     def logits(self, params, batch=256):

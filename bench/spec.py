@@ -76,7 +76,8 @@ def metric_reader(name: str):
 def family(name: str):
     """The module ``bench/families/<name>.py`` of a tier-model family, with
     ``register(name, spec, registry)``, ``reference(spec, num_classes,
-    image)`` and ``forward_flops(spec, image, num_classes)``. Loaded once:
+    image)``, ``forward_flops(spec, image, num_classes)`` and
+    ``param_count(spec, image, num_classes)``. Loaded once:
     the operation count is looked up for every pair of every round."""
     if not (BENCH / "families" / f"{name}.py").is_file():
         known = sorted(p.stem for p in (BENCH / "families").glob("*.py"))

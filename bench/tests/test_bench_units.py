@@ -61,17 +61,39 @@ def test_every_per_layer_metric_has_a_reader(metric):
               "compiles_in_window": 0, "dispatches_per_round": 15.0}
     value = read(record)
     assert value is None or value >= 0
-    traced = dict(record, sched_ms=3.0, algo_ms=4.0, peak_flops=1e12,
+    traced = dict(record, sched_ms=3.0, algo_ms=4.0,
                   span_ms={"bsbodp.student": 1.5, "bsbodp.unstack": 0.5,
                            "bsbodp.inputs": 0.7, "round": 9.0},
                   counters={'fl_jit_calls_total{step="decode"}': 300.0,
                             'fl_jit_calls_total{step="student"}': 300.0,
                             'fl_stack_calls_total{step="stack"}': 20.0},
+                  memory_peak_bytes=2_040_982_016, peaks=spec.peaks("TPU v5 lite"),
                   trace={"ops": 1e9, "window_s": 2.0, "busy_s": 0.5, "launches": 8,
                          "rounds": 1,
                          "by_program": {"jit_bsbodp_student": {"device_s": 0.25,
-                                                               "launches": 2}}})
+                                                               "launches": 2}},
+                         "work": {"resnet10": {"student": {"flops": 1e9, "bytes": 1e8}}}})
     assert read(traced) >= 0
+
+
+@pytest.mark.parametrize("metric,value", [
+    ("peak_hbm_gb", 2.040982016),
+    # least time 0.25 s (bytes) + 0.04 s (operations) of 0.5 s on the device
+    ("bsbodp_student_roofline", 58.0)])
+def test_peak_memory_and_the_student_roofline_read_a_made_up_record(metric, value):
+    read = spec.metric_reader(metric)
+    peaks = {"bf16_flops": 1e12, "hbm_bytes_per_s": 1e9}
+    record = {"memory_peak_bytes": 2_040_982_016, "peaks": peaks, "trace": {
+        "rounds": 1,
+        "by_program": {"jit_bsbodp_student": {"device_s": 0.5, "launches": 20},
+                       "jit_bsbodp_teacher": {"device_s": 9.0, "launches": 20}},
+        "work": {"resnet10": {"student": {"flops": 1e10, "bytes": 2.5e8},
+                              "teacher": {"flops": 9e12, "bytes": 9e12}},
+                 "resnet18": {"student": {"flops": 4e10, "bytes": 1e6}},
+                 "cnn1": {"student_leaf": {"flops": 9e12, "bytes": 9e12}}}}}
+    assert read(record) == pytest.approx(value)
+    assert read({"memory_peak_bytes": 0, "trace": {"rounds": 1, "by_program": {},
+                                                   "work": {}}}) is None
 
 
 @pytest.mark.parametrize("metric,value", [
@@ -102,6 +124,16 @@ C50E5 = spec.load_json(spec.BENCH / "configs" / "fedeec-cifar10-c50e5.json")
                                        ("resnet18_w64", 963_718_656)])
 def test_operations_by_family_are_the_c50e5_models_counts(model, ops):
     assert flops.forward_flops(C50E5["models"][model], 32, 10) == ops
+
+
+@pytest.mark.parametrize("family,spec_", [
+    ("cnn", {"family": "cnn", "widths": [8, 16, 32]}),
+    ("resnet", {"family": "resnet", "blocks_per_stage": [2, 1], "width": 16})])
+def test_parameter_counts_are_the_programs(family, spec_):
+    harness.register_models({"models": {f"count_{family}": spec_}})
+    init, _ = FL_MODELS.pop(f"count_{family}")
+    params = init(jax.random.PRNGKey(0), 10, 16)
+    assert flops.param_count(spec_, 16, 10) == sum(x.size for x in jax.tree.leaves(params))
 
 
 @pytest.mark.parametrize("lookup", [

@@ -50,12 +50,27 @@ def _with_a_device(reduce_profile):
     return reduce
 
 
-def test_a_traced_run_reads_the_programs_spans_counters_and_programs(monkeypatch):
-    monkeypatch.setattr(trace_reduce, "reduce_profile",
-                        _with_a_device(trace_reduce.reduce_profile))
-    # the peaks table knows no CPU: the made-up device plane stands for a v5e
-    out = harness.run_cell(tiny_cell(), SEED, 0.5, True, time.perf_counter(),
-                           dict(CPU, kind="TPU v5 lite"))
+@pytest.fixture(scope="module")
+def traced():
+    """A traced run's result and the ``record`` its metrics were read from."""
+    records = []
+    read = harness._read_metric
+
+    def keep(name, record):
+        records.append(record)
+        return read(name, record)
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(trace_reduce, "reduce_profile", _with_a_device(trace_reduce.reduce_profile))
+        mp.setattr(harness, "_read_metric", keep)
+        # the peaks table knows no CPU: the made-up device plane stands for a v5e
+        out = harness.run_cell(tiny_cell(), SEED, 0.5, True, time.perf_counter(),
+                               dict(CPU, kind="TPU v5 lite"))
+    return out, records[0]
+
+
+def test_a_traced_run_reads_the_programs_spans_counters_and_programs(traced):
+    out, _ = traced
     m = {k: v["value"] for k, v in out["metrics"].items()}
     for name in ("student_host_ms_per_round", "unstack_host_ms_per_round",
                  "inputs_host_ms_per_round"):
@@ -66,3 +81,17 @@ def test_a_traced_run_reads_the_programs_spans_counters_and_programs(monkeypatch
     assert calls > 0 and calls % 3 == 0
     assert m["student_device_ms_per_round"] == pytest.approx(1e-4)
     assert out["correct"] is True
+
+
+def test_the_profiled_rounds_work_by_model_and_step_sums_to_its_operations(traced):
+    trace = traced[1]["trace"]
+    work = trace["work"]
+    assert set(work) == {"decoder", "tiny_cnn", "tiny_resnet_a", "tiny_resnet_b"}
+    assert set(work["tiny_resnet_b"]) == {"teacher", "student", "eval"}
+    assert set(work["tiny_cnn"]) == {"teacher", "student_leaf"}
+    assert all(w["flops"] > 0 for m in work.values() for w in m.values())
+    assert {(m, k) for m, ws in work.items() for k, w in ws.items() if not w["bytes"]} == {
+        ("decoder", "decode")}
+    # the round's operations as the harness counted them before it kept
+    # them by model and step
+    assert trace["ops"] == sum(w["flops"] for m in work.values() for w in m.values()) == 64_578_560
